@@ -181,9 +181,15 @@ def make_reducer_for(c, meta_dtype: str = "float32",
     """Build a reducer from a bare ``CommConfig`` — the topology subsystem
     instantiates one per edge class (intra-group / cross-group / gossip
     neighbor), each with its own scheme. ``aggregate`` installs the
-    robust aggregation hook (repro.robust) on the underlying reducer."""
+    robust aggregation hook (repro.robust) on the underlying reducer.
+    An unset ``c.use_pallas`` is resolved from the platform here."""
+    from dataclasses import replace
+
     from repro.comm.quant import QuantReducer
     from repro.comm.topk import TopKReducer
+    from repro.kernels.ops import resolve_use_pallas
+
+    c = replace(c, use_pallas=resolve_use_pallas(c.use_pallas))
 
     if c.scheme == "dense":
         r = DenseReducer(meta_dtype=meta_dtype)

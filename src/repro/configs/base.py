@@ -231,8 +231,9 @@ class CommConfig:
                     block-momentum update stays unbiased (EF-SGD)
     chunk_rows      rows of the (rows, 128) wire layout sharing one f32
                     quantization scale (chunk = chunk_rows * 128 values)
-    use_pallas      route quant/dequant through the Pallas kernels
-                    (interpret mode off-TPU) instead of the jnp reference
+    use_pallas      route quant/dequant through the Pallas kernels instead
+                    of the jnp reference; None = on TPU only
+                    (kernels.ops.resolve_use_pallas)
     seed            stochastic-rounding PRNG stream
     """
 
@@ -240,7 +241,7 @@ class CommConfig:
     k_frac: float = 0.1
     error_feedback: bool = True
     chunk_rows: int = 64
-    use_pallas: bool = False
+    use_pallas: Optional[bool] = None
     seed: int = 0
 
     def __post_init__(self):
@@ -544,7 +545,9 @@ class MAvgConfig:
     # default f32 for CPU experiments, bf16 for TPU launch configs
     meta_dtype: str = "float32"
     compute_dtype: str = "float32"
-    use_pallas: bool = False  # Pallas kernels on TPU; jnp ref elsewhere
+    # Pallas kernels for the meta update; None = on TPU only, the jnp
+    # reference elsewhere (kernels.ops.resolve_use_pallas)
+    use_pallas: Optional[bool] = None
     # packed flat meta-plane (repro.pack, DESIGN.md §9): the whole param
     # pytree rides as ONE lane-aligned (rows, 128) buffer, so every
     # meta-phase op is a constant number of whole-model kernel passes
@@ -559,6 +562,12 @@ class MAvgConfig:
     # interactive/debug paths (and any caller that re-reads the
     # pre-step state) need.
     donate: bool = True
+    # local phase one learner after another (lax.map) instead of vmapped
+    # over the learner axis: the compiled program holds one learner's
+    # unpacked tree, gradients and activations instead of L. For several
+    # learners on one device (launch/train.py sets it without a mesh);
+    # left off where the learner axis is sharded over devices. Same math.
+    sequential_learners: bool = False
     # in-step finite guard (repro.chaos / DESIGN.md §13): after the local
     # phase (and any injected payload corruption), learners whose planes
     # carry NaN/Inf are reset to the broadcast global params (zero

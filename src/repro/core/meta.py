@@ -196,8 +196,9 @@ def _local_phase(loss_fn: LossFn, learners, local_mom, batches, cfg: MAvgConfig,
     """
     if spec is not None:
         ldt = _ldtype(learners)
-        unpack = lambda b: spec.unpack(b, dtype=b.dtype)
-        repack = lambda t: spec.pack(t, dtype=ldt)
+        # a None momentum (no learner-level momentum) stays None
+        unpack = lambda b: None if b is None else spec.unpack(b, dtype=b.dtype)
+        repack = lambda t: None if t is None else spec.pack(t, dtype=ldt)
     else:
         unpack = repack = lambda t: t
 
@@ -257,12 +258,22 @@ def _local_phase(loss_fn: LossFn, learners, local_mom, batches, cfg: MAvgConfig,
         return (repack(w), repack(mom),
                 (losses * act).sum(), (gnorms * act).sum(), act.sum())
 
-    mom_in = tree_zeros_like(learners) if local_mom is None else local_mom
+    # without learner-level momentum the carry holds no momentum stack at
+    # all (an all-zero (L, rows, 128) plane carried through the scan would
+    # cost one learner-plane copy of memory and match the learners'
+    # sharding only by accident); with local_momentum but no persistent
+    # stack the momentum restarts from zero every block
+    mom_in = local_mom
+    if mom_in is None and cfg.local_momentum > 0.0:
+        mom_in = tree_zeros_like(learners)
+    over_learners = _in_sequence if cfg.sequential_learners else jax.vmap
     if steps is None:
-        w, mom, loss_l, gnorm = jax.vmap(one_learner)(learners, mom_in, batches)
+        w, mom, loss_l, gnorm = over_learners(one_learner)(
+            learners, mom_in, batches
+        )
         loss, gnorm = loss_l.mean(), gnorm.mean()
     else:
-        w, mom, lsum, gsum, asum = jax.vmap(one_learner_masked)(
+        w, mom, lsum, gsum, asum = over_learners(one_learner_masked)(
             learners, mom_in, batches, steps
         )
         denom = jnp.maximum(asum.sum(), 1.0)
@@ -274,6 +285,12 @@ def _local_phase(loss_fn: LossFn, learners, local_mom, batches, cfg: MAvgConfig,
     active = None if steps is None else (asum > 0)
     return (w, (mom if local_mom is not None else None), loss, gnorm,
             loss_l, active)
+
+
+def _in_sequence(fn):
+    """``fn`` over the leading (learner) axis of its arguments, one slice
+    after another (``lax.map``) — the counterpart of ``jax.vmap(fn)``."""
+    return lambda *xs: lax.map(lambda x: fn(*x), xs)
 
 
 def _learner_finite_mask(tree):

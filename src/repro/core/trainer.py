@@ -1,10 +1,11 @@
 """High-level training driver tying together model, data, meta-optimizer,
 telemetry, checkpointing and (optionally) a device mesh.
 
-On a real cluster the same Trainer runs under the production mesh from
-``repro.launch.mesh`` (the learner axis sharded over data/pod axes); on CPU
-it runs the identical jitted program on one device — the SPMD program is
-the same, which is what the multi-pod dry-run proves.
+Given a ``mesh`` and ``state_shardings`` (``repro.launch.specs``), the
+initial state is placed on them and the jitted step keeps it there (the
+learner axis sharded over the mesh's data axes — ``launch/train.py
+--mesh host`` builds one over the chips of a host); without them the same
+jitted program runs on the default device.
 
 Telemetry (``repro.obs``, DESIGN.md §11): every per-step scalar the meta
 step emits is written into an on-device MetricsBuffer ring *inside* the
@@ -16,12 +17,14 @@ structured run log under a per-run manifest.
 """
 from __future__ import annotations
 
+import contextlib
 import os
 import time
 from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.checkpoint import load_state, save_state
 from repro.configs.base import MAvgConfig, TrainConfig
@@ -106,7 +109,16 @@ class Trainer:
         from repro.topology import make_topology
 
         self._topology = make_topology(self.mcfg)
-        self.state = init_state(params, self.mcfg, topology=self._topology)
+        if self._state_shardings is None:
+            self.state = init_state(params, self.mcfg,
+                                    topology=self._topology)
+        else:
+            # built straight into its shardings: no device ever holds
+            # the whole learner stack
+            self.state = jax.jit(
+                lambda p: init_state(p, self.mcfg, topology=self._topology),
+                out_shardings=self._state_shardings,
+            )(params)
         self._step_fn = make_meta_step(
             loss_fn, self.mcfg, topology=self._topology,
             chaos=chaos_corruptor,
@@ -170,6 +182,13 @@ class Trainer:
         self._mkeys = metric_keys(metrics_sds)
         capacity = obs.buffer_capacity or max(self.cfg.log_every, 1)
         self._mb = MetricsBuffer(self._mkeys, capacity)
+        rep = None
+        if self._state_shardings is not None:
+            # the ring lives replicated on the mesh from the first step:
+            # a single-device ring would come back mesh-sharded and the
+            # second step would compile the program again
+            rep = NamedSharding(self.mesh, PartitionSpec())
+            self._mb.buf = jax.device_put(self._mb.buf, rep)
 
         from repro.launch.specs import meta_step_jit_kwargs
 
@@ -178,6 +197,7 @@ class Trainer:
             self._state_shardings,
             n_extra_args=4,
             donate_extra=(_RING_ARGNUM,),
+            replicated=rep,
         )
         self._fused = jax.jit(fused, **kwargs)
 
@@ -185,17 +205,15 @@ class Trainer:
         if obs.cost_analysis:
             from repro.roofline.hlo_cost import jit_cost
 
-            try:
-                # the bare (state, batches, lr) step, not the fused one:
-                # the metric ring is telemetry, not part of the training
-                # program whose HBM/peak-state cost the manifest records
-                jc = jit_cost(
-                    lambda s, b, l: self._step_fn(s, b, lr=l),
-                    self.state, batches, lr,
-                    **({"donate_argnums": (0,)} if self.mcfg.donate else {}),
-                )
-            except Exception:  # cost analysis is best-effort telemetry
-                jc = None
+            # the bare (state, batches, lr) step, not the fused one: the
+            # metric ring is telemetry, not part of the training program
+            # whose HBM/peak-state cost the manifest records. Asked for
+            # and failing is an error, not a manifest without the numbers.
+            jc = jit_cost(
+                lambda s, b, l: self._step_fn(s, b, lr=l),
+                self.state, batches, lr,
+                **({"donate_argnums": (0,)} if self.mcfg.donate else {}),
+            )
         self.manifest = run_manifest(
             train_cfg=self.cfg,
             mcfg=self.mcfg,
@@ -214,16 +232,39 @@ class Trainer:
             # modeled bytes — the training state is untouched
             from repro.obs import measured_peak_gbps, profile_phases
 
-            try:
-                self.attribution = profile_phases(
-                    self.loss_fn, self.mcfg, self.state, batches, lr,
-                    iters=5, warmup=2, peak_gbps=measured_peak_gbps(),
-                )
-            except Exception:  # attribution is best-effort telemetry
-                self.attribution = []
+            self.attribution = profile_phases(
+                self.loss_fn, self.mcfg, self.state, batches, lr,
+                iters=5, warmup=2, peak_gbps=measured_peak_gbps(),
+            )
             if self._sink is not None:
                 for row in self.attribution:
                     self._sink.append(row)
+
+    def compiled_step(self):
+        """The compiled executable of the fused step for the current
+        state (``.as_text()``, ``.memory_analysis()``). After ``run`` this
+        is the program the steps ran: lowering hits jit's cache, and the
+        lowered computation keeps its compiled executable."""
+        assert self._fused is not None, "run() at least one step first"
+        step = int(self.state.step)
+        # inputs made as run() makes them, mesh context included (it is
+        # part of their abstract types, and so of jit's cache key)
+        with self._mesh_context():
+            batches = self.batch_fn(
+                jax.random.fold_in(self.data_rng, step), step
+            )
+            lr = (self.lr_schedule(step) if self.lr_schedule
+                  else jnp.float32(self.mcfg.learner_lr))
+            return self._fused.lower(
+                self.state, batches, lr, self._mb.buf, self._mb.row_index()
+            ).compile()
+
+    def _mesh_context(self):
+        """The mesh in context while the step is traced: kernels that XLA
+        cannot partition wrap themselves in ``shard_map`` over it."""
+        if self.mesh is None:
+            return contextlib.nullcontext()
+        return jax.set_mesh(self.mesh)
 
     # ------------------------------------------------------------------
     # driving loop
@@ -327,7 +368,8 @@ class Trainer:
             os.path.join(run_dir, "jax_trace")
             if self.obs_cfg.profiler and run_dir else None
         )
-        with self.tracer.session(export_path, profiler_dir):
+        with self.tracer.session(export_path, profiler_dir), \
+                self._mesh_context():
             try:
                 for i in range(n):
                     step = start + i

@@ -4,5 +4,7 @@ from repro.data.synthetic import (
     classif_eval_set,
     lm_batch_fn,
     lm_eval_set,
+    lm_teacher,
     sample_lm,
+    sparse_bigram_teacher,
 )

@@ -30,15 +30,50 @@ def bigram_table(seed: int, vocab: int, concentration: float = 0.3):
     return jax.nn.softmax(logits, axis=-1)
 
 
+# largest vocabulary the dense (V, V) bigram table is built for (64 MiB in
+# f32); a full-width vocabulary (50k tokens: a 10 GB table) gets the
+# sparse teacher below instead
+DENSE_TEACHER_MAX_VOCAB = 4096
+SPARSE_TEACHER_SUPPORT = 64
+
+
+def sparse_bigram_teacher(seed: int, vocab: int,
+                          support: int = SPARSE_TEACHER_SUPPORT,
+                          concentration: float = 0.3):
+    """Bigram teacher for a large vocabulary: each token moves to one of
+    ``support`` random successors, drawn with low-entropy logits.
+    Returns (successors (V, support) int32, logits (V, support) f32) —
+    O(V * support) memory instead of the dense table's O(V^2)."""
+    k_s, k_l = jax.random.split(jax.random.PRNGKey(seed))
+    succ = jax.random.randint(k_s, (vocab, support), 0, vocab, jnp.int32)
+    logits = jax.random.normal(k_l, (vocab, support)) / concentration
+    return succ, logits
+
+
+def lm_teacher(seed: int, vocab: int):
+    """The dense bigram table up to ``DENSE_TEACHER_MAX_VOCAB`` tokens,
+    the sparse teacher above it; either one feeds ``sample_lm``."""
+    if vocab <= DENSE_TEACHER_MAX_VOCAB:
+        return bigram_table(seed, vocab)
+    return sparse_bigram_teacher(seed, vocab)
+
+
 @partial(jax.jit, static_argnums=(2, 3))
 def sample_lm(key, table, batch: int, seq_len: int):
-    """Sample (batch, seq_len) token sequences from the bigram teacher."""
+    """Sample (batch, seq_len) token sequences from a bigram teacher: the
+    dense (V, V) table or a ``sparse_bigram_teacher`` pair."""
     k0, k1 = jax.random.split(key)
-    vocab = table.shape[0]
+    sparse = isinstance(table, tuple)
+    vocab = table[0].shape[0] if sparse else table.shape[0]
     first = jax.random.randint(k0, (batch,), 0, vocab)
 
     def step(tok, k):
-        nxt = jax.random.categorical(k, jnp.log(table[tok] + 1e-9))
+        if sparse:
+            succ, logits = table
+            j = jax.random.categorical(k, logits[tok])
+            nxt = jnp.take_along_axis(succ[tok], j[:, None], axis=1)[:, 0]
+        else:
+            nxt = jax.random.categorical(k, jnp.log(table[tok] + 1e-9))
         return nxt, nxt
 
     ks = jax.random.split(k1, seq_len - 1)
@@ -50,7 +85,7 @@ def sample_lm(key, table, batch: int, seq_len: int):
 def lm_batch_fn(model_cfg: ModelConfig, num_learners: int, k_steps: int,
                 batch: int, seq_len: int, table_seed: int = 1234):
     """Returns ``batch_fn(rng, step)`` producing (L, K, B, S) token batches."""
-    table = bigram_table(table_seed, model_cfg.vocab_size)
+    table = lm_teacher(table_seed, model_cfg.vocab_size)
 
     def batch_fn(rng, step):
         ks = jax.random.split(rng, num_learners * k_steps)
@@ -116,6 +151,6 @@ def classif_eval_set(d_in: int, classes: int, n: int = 2048, teacher_seed: int =
 
 def lm_eval_set(model_cfg: ModelConfig, n: int = 64, seq_len: int = 64,
                 table_seed: int = 1234, seed: int = 98):
-    table = bigram_table(table_seed, model_cfg.vocab_size)
+    table = lm_teacher(table_seed, model_cfg.vocab_size)
     toks = sample_lm(jax.random.PRNGKey(seed), table, n, seq_len)
     return {"tokens": toks, "labels": toks}

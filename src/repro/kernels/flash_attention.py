@@ -21,6 +21,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
@@ -130,11 +131,4 @@ def flash_attention_bhsd(q, k, v, *, causal=True, sliding_window=0,
 
 
 def _vmem(shape, dtype):
-    try:  # TPU backend
-        from jax.experimental.pallas import tpu as pltpu
-
-        return pltpu.VMEM(shape, dtype)
-    except Exception:  # pragma: no cover
-        import jax.experimental.pallas as pl_mod
-
-        return pl_mod.MemoryRef(shape, dtype)
+    return pltpu.VMEM(shape, dtype)

@@ -1,9 +1,14 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 Dispatch policy: on TPU the compiled kernels run natively; everywhere else
-(this CPU container, tests) they run in ``interpret=True`` mode, which
-executes the same kernel body per-block in Python/XLA — bit-comparable
-logic, no TPU required. The pure-jnp oracles live in ref.py.
+(CPU runs, tests) they run in ``interpret=True`` mode, which executes the
+same kernel body per-block in Python/XLA — bit-comparable logic, no TPU
+required. The pure-jnp oracles live in ref.py.
+
+Whether the meta path uses the kernels at all is the ``use_pallas`` field
+of ``MAvgConfig``/``CommConfig``; left at None it is decided here from the
+platform (``resolve_use_pallas``): kernels on TPU, the jnp oracle
+elsewhere.
 """
 from __future__ import annotations
 
@@ -27,6 +32,12 @@ LANES = 128
 
 def _default_interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def resolve_use_pallas(flag) -> bool:
+    """A config's ``use_pallas``: an explicit True/False is kept; None
+    means "compiled kernels on TPU, the jnp oracle elsewhere"."""
+    return jax.default_backend() == "tpu" if flag is None else bool(flag)
 
 
 # ---------------------------------------------------------------------------

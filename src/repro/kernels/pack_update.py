@@ -40,6 +40,10 @@ from jax.experimental import pallas as pl
 
 BLOCK_ROWS = 64  # scale-chunk rows, matching quantize.py's wire layout
 LANES = 128
+# one chunk's scale in the kernels' (L, nchunks, 1, 1) scales array: the
+# TPU lowering takes a block whose last two dims are (8, 128) multiples or
+# the array's own, and XLA stores that shape densely
+SCALE_BLOCK = (1, 1, 1, 1)
 EPS = 1e-12  # all-zero chunks (e.g. pure padding): finite scale, q = 0
 
 
@@ -52,7 +56,7 @@ def _kernel(w_ref, g_ref, *rest, qmax: int, has_residual: bool):
     if has_residual:
         d = d + e_ref[...].astype(jnp.float32)
     scale = jnp.maximum(jnp.max(jnp.abs(d)), EPS) / qmax
-    s_ref[0, 0] = scale
+    s_ref[...] = jnp.broadcast_to(scale, s_ref.shape)
     q = jnp.clip(jnp.floor(d / scale + u_ref[...]), -qmax, qmax)
     c = q * scale
     c_ref[...] = c
@@ -85,7 +89,7 @@ def pack_update_3d(w, g, e, u, *, qmax: int = 127, block: int | None = None,
     grid = (L, rows // b)
     spec = pl.BlockSpec((1, b, LANES), lambda l, i: (l, i, 0))
     g_spec = pl.BlockSpec((b, LANES), lambda l, i: (i, 0))
-    s_spec = pl.BlockSpec((1, 1), lambda l, i: (l, i))
+    s_spec = pl.BlockSpec(SCALE_BLOCK, lambda l, i: (l, i, 0, 0))
     in_specs = [spec, g_spec] + ([spec] if e is not None else []) + [spec]
     args = (w, g) + ((e,) if e is not None else ()) + (u,)
     c, err, scales = pl.pallas_call(
@@ -96,11 +100,11 @@ def pack_update_3d(w, g, e, u, *, qmax: int = 127, block: int | None = None,
         out_shape=[
             jax.ShapeDtypeStruct(w.shape, jnp.float32),
             jax.ShapeDtypeStruct(w.shape, jnp.float32),
-            jax.ShapeDtypeStruct((L, rows // b), jnp.float32),
+            jax.ShapeDtypeStruct((L, rows // b, 1, 1), jnp.float32),
         ],
         interpret=interpret,
     )(*args)
-    return c, err, scales
+    return c, err, scales.reshape(L, rows // b)
 
 
 # ---------------------------------------------------------------------------
@@ -115,7 +119,7 @@ def _compress_kernel(d_ref, u_ref, *out, qmax: int, with_err: bool):
         c_ref, s_ref = out
     d = d_ref[...].astype(jnp.float32)
     scale = jnp.maximum(jnp.max(jnp.abs(d)), EPS) / qmax
-    s_ref[0, 0] = scale
+    s_ref[...] = jnp.broadcast_to(scale, s_ref.shape)
     q = jnp.clip(jnp.floor(d / scale + u_ref[...]), -qmax, qmax)
     c = q * scale
     c_ref[...] = c
@@ -151,9 +155,9 @@ def pack_compress_3d(d, u, *, qmax: int = 127, block: int | None = None,
     assert rows % b == 0, (rows, b)
     grid = (L, rows // b)
     spec = pl.BlockSpec((1, b, LANES), lambda l, i: (l, i, 0))
-    s_spec = pl.BlockSpec((1, 1), lambda l, i: (l, i))
+    s_spec = pl.BlockSpec(SCALE_BLOCK, lambda l, i: (l, i, 0, 0))
     plane = jax.ShapeDtypeStruct(d.shape, jnp.float32)
-    scales = jax.ShapeDtypeStruct((L, rows // b), jnp.float32)
+    scales = jax.ShapeDtypeStruct((L, rows // b, 1, 1), jnp.float32)
     out = pl.pallas_call(
         functools.partial(_compress_kernel, qmax=qmax, with_err=with_err),
         grid=grid,
@@ -162,7 +166,5 @@ def pack_compress_3d(d, u, *, qmax: int = 127, block: int | None = None,
         out_shape=[plane, plane, scales] if with_err else [plane, scales],
         interpret=interpret,
     )(d, u)
-    if with_err:
-        return out
-    c, s = out
-    return c, None, s
+    c, err, s = out if with_err else (out[0], None, out[1])
+    return c, err, s.reshape(L, rows // b)

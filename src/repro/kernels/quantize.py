@@ -20,6 +20,9 @@ statistical.
 Layout: callers flatten each leaf to (rows, 128) with rows % 8 == 0
 (ops.py pads); the grid walks chunks of ``block`` rows and chunk i owns
 scale[i] = max|x_chunk| / qmax — the issue's "per-chunk f32 scales".
+Inside the kernels the scales are an (nchunks, 1, 1) array in (1, 1, 1)
+blocks: the TPU lowering takes a block whose last two dims are (8, 128)
+multiples or the array's own, and XLA stores that shape densely.
 """
 from __future__ import annotations
 
@@ -42,16 +45,19 @@ def choose_block(rows: int, block: int | None = None) -> int:
     return b
 
 
+SCALE_BLOCK = (1, 1, 1)  # one chunk's scale in the (nchunks, 1, 1) array
+
+
 def _quant_kernel(x_ref, u_ref, q_ref, s_ref, *, qmax: int):
     x = x_ref[...].astype(jnp.float32)
     scale = jnp.maximum(jnp.max(jnp.abs(x)), EPS) / qmax
-    s_ref[0, 0] = scale
+    s_ref[...] = jnp.broadcast_to(scale, s_ref.shape)
     q = jnp.floor(x / scale + u_ref[...])
     q_ref[...] = jnp.clip(q, -qmax, qmax).astype(q_ref.dtype)
 
 
 def _dequant_kernel(q_ref, s_ref, out_ref):
-    out_ref[...] = q_ref[...].astype(jnp.float32) * s_ref[0, 0]
+    out_ref[...] = q_ref[...].astype(jnp.float32) * s_ref[0]
 
 
 def quantize_2d(x, u, *, qmax: int = 127, block: int | None = None,
@@ -65,18 +71,19 @@ def quantize_2d(x, u, *, qmax: int = 127, block: int | None = None,
     b = choose_block(rows, block)
     grid = (rows // b,)
     spec = pl.BlockSpec((b, LANES), lambda i: (i, 0))
-    scale_spec = pl.BlockSpec((1, 1), lambda i: (i, 0))
-    return pl.pallas_call(
+    scale_spec = pl.BlockSpec(SCALE_BLOCK, lambda i: (i, 0, 0))
+    q, scales = pl.pallas_call(
         functools.partial(_quant_kernel, qmax=qmax),
         grid=grid,
         in_specs=[spec, spec],
         out_specs=[spec, scale_spec],
         out_shape=[
             jax.ShapeDtypeStruct(x.shape, jnp.int8),
-            jax.ShapeDtypeStruct((rows // b, 1), jnp.float32),
+            jax.ShapeDtypeStruct((rows // b, 1, 1), jnp.float32),
         ],
         interpret=interpret,
     )(x, u)
+    return q, scales.reshape(rows // b, 1)
 
 
 def dequantize_2d(q, scales, *, block: int | None = None,
@@ -90,7 +97,7 @@ def dequantize_2d(q, scales, *, block: int | None = None,
     del block  # implied by the scales shape
     grid = (rows // b,)
     spec = pl.BlockSpec((b, LANES), lambda i: (i, 0))
-    scale_spec = pl.BlockSpec((1, 1), lambda i: (i, 0))
+    scale_spec = pl.BlockSpec(SCALE_BLOCK, lambda i: (i, 0, 0))
     return pl.pallas_call(
         _dequant_kernel,
         grid=grid,
@@ -98,4 +105,4 @@ def dequantize_2d(q, scales, *, block: int | None = None,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
         interpret=interpret,
-    )(q, scales)
+    )(q, scales.reshape(nchunks, 1, 1))

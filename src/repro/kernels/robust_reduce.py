@@ -9,8 +9,9 @@ rest). Done naively that is a full-plane sort materialized in HBM plus a
 second reduction pass. This kernel streams one (L, block, 128) VMEM tile
 per grid step — the whole learner axis is resident, which is exactly why
 the learner axis is the leading one in the packed layout — sorts along L
-in-register, and writes only the (block, 128) aggregate: one read of the
-stack, one write of the result, and XLA cannot re-split it.
+in-register (a min/max sorting network), and writes only the (block, 128)
+aggregate: one read of the stack, one write of the result, and XLA cannot
+re-split it.
 
 ``trim=0`` takes a static branch that skips the sort entirely and emits
 ``sum / L`` in the same reduction order as ``jnp.mean(x, axis=0)`` — the
@@ -41,6 +42,19 @@ def median_trim(L: int) -> int:
     return (L - 1) // 2
 
 
+def _sort_learners(x):
+    """Sort the static learner axis of an (L, block, 128) tile with an
+    odd-even transposition network of elementwise min/max — the TPU
+    kernel lowering has no sort. For finite inputs the result is exactly
+    ``jnp.sort(x, axis=0)``."""
+    v = [x[j] for j in range(x.shape[0])]
+    for r in range(len(v)):
+        for j in range(r % 2, len(v) - 1, 2):
+            v[j], v[j + 1] = (jnp.minimum(v[j], v[j + 1]),
+                              jnp.maximum(v[j], v[j + 1]))
+    return jnp.stack(v)
+
+
 def _kernel(x_ref, o_ref, *, trim: int):
     x = x_ref[...].astype(jnp.float32)  # (L, block, 128)
     L = x.shape[0]
@@ -49,7 +63,7 @@ def _kernel(x_ref, o_ref, *, trim: int):
         # the bitwise mean-parity contract
         o_ref[...] = jnp.sum(x, axis=0) / L
     else:
-        s = jnp.sort(x, axis=0)
+        s = _sort_learners(x)
         kept = jnp.sum(s[trim:L - trim], axis=0)
         o_ref[...] = kept / (L - 2 * trim)
 

@@ -44,8 +44,6 @@ def main() -> int:
                     help="label suffix for perf-iteration results")
     ap.add_argument("--remat", default="full",
                     choices=["full", "dots", "everything"])
-    ap.add_argument("--mlstm-chunk", type=int, default=0,
-                    help="chunkwise-parallel mLSTM chunk length (0=recurrent)")
     ap.add_argument("--k", type=int, default=2,
                     help="K local steps per meta-step in the lowered program")
     ap.add_argument("--expert-axis", default="",
@@ -63,10 +61,6 @@ def main() -> int:
         from repro.models import transformer
 
         transformer.set_remat_policy(args.remat)
-    if args.mlstm_chunk:
-        from repro.models import xlstm
-
-        xlstm.set_mlstm_chunk(args.mlstm_chunk)
     if args.expert_axis:
         from repro.models import moe
 

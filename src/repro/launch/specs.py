@@ -96,7 +96,8 @@ def _batch_axes(mesh, batch: int):
 
 def meta_step_jit_kwargs(mcfg: MAvgConfig, state_shardings=None,
                          n_extra_args: int = 2,
-                         donate_extra: tuple = ()) -> dict:
+                         donate_extra: tuple = (),
+                         replicated=None) -> dict:
     """jax.jit kwargs for a ``step(state, batches, ...)`` meta step.
 
     One assembly point so every launcher agrees on the two coupled
@@ -112,8 +113,14 @@ def meta_step_jit_kwargs(mcfg: MAvgConfig, state_shardings=None,
       layout stable across steps, donation or not.)
 
     ``n_extra_args`` counts the non-state positional args (batches, lr,
-    and the telemetry ring under repro.obs) which stay unsharded /
-    unconstrained. ``donate_extra`` names additional loop-carried argnums
+    and the telemetry ring under repro.obs). The batches keep the
+    sharding they arrive with; the args after them and the step's
+    non-state output take ``replicated`` (a replicated sharding on the
+    state's mesh) when given, else stay unconstrained. Pinning them
+    matters for loop-carried outputs such as the ring: left to the
+    compiler, it comes back with another sharding than it went in with,
+    and the next step compiles again. ``donate_extra`` names additional
+    loop-carried argnums
     to donate regardless of ``mcfg.donate`` — the Trainer's on-device
     MetricsBuffer ring rides here (DESIGN.md §11): the caller never
     re-reads a pre-step ring, so its row write is always safe to do in
@@ -123,8 +130,9 @@ def meta_step_jit_kwargs(mcfg: MAvgConfig, state_shardings=None,
 
     kwargs = {}
     if state_shardings is not None:
-        kwargs["in_shardings"] = (state_shardings,) + (None,) * n_extra_args
-        kwargs["out_shardings"] = (state_shardings, None)
+        rest = (replicated,) * (n_extra_args - 1)
+        kwargs["in_shardings"] = (state_shardings, None) + rest
+        kwargs["out_shardings"] = (state_shardings, replicated)
     donate = ((STATE_ARGNUM,) if mcfg.donate else ()) + tuple(donate_extra)
     if donate:
         kwargs["donate_argnums"] = donate

@@ -1,23 +1,29 @@
 """Training launcher: end-to-end M-AVG training of an assigned architecture
-(reduced or full config) on whatever devices are available.
+(reduced or full-width config) on the devices of one host.
 
-On CPU this trains the reduced config with a small learner count (the
-end-to-end example driver); on a real TPU pod, pass --full and the
-production mesh from mesh.py is used with the learner axis sharded over
-'data' (the jitted program is identical — that is what the dry-run
-proves).
+Without ``--full`` it trains the reduced config (the end-to-end example
+driver on CPU). ``--full`` keeps the published widths; xlstm-350m at full
+width fits one 16 GiB v5e chip with bf16 learner copies
+(``--compute-dtype bfloat16``). ``--mesh host`` lays the learners over
+every chip of the host, one (n, 1) ('data', 'model') mesh from
+``launch/mesh.py``, with the learner stack and the batches sharded on
+'data'; without it everything runs on the default device. No pod-scale
+mesh is built here — the 256/512-chip programs are compiled, not run, by
+``launch/dryrun.py``.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.train --arch qwen3-1.7b \
       --algorithm mavg --learners 4 --k 4 --steps 50
+  PYTHONPATH=src python -m repro.launch.train --arch xlstm-350m --full \
+      --learners 2 --k 2 --steps 3 --batch 4 --seq 512 \
+      --compute-dtype bfloat16
 """
 from __future__ import annotations
 
 import argparse
-from functools import partial
 
 import jax
-import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import (
     ALGORITHMS,
@@ -44,7 +50,7 @@ from repro.optim import warmup_cosine
 from repro.pack import unpack_params
 
 
-def main() -> None:
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-1.7b")
     # choices derive from the configs/base.py constants so new algorithms /
@@ -58,7 +64,18 @@ def main() -> None:
     ap.add_argument("--lr", type=float, default=0.3)
     ap.add_argument("--momentum", type=float, default=0.7)
     ap.add_argument("--full", action="store_true",
-                    help="full-scale config (TPU pod required)")
+                    help="full-width config (published widths); "
+                         "xlstm-350m fits one v5e chip with "
+                         "--compute-dtype bfloat16")
+    ap.add_argument("--compute-dtype", default="float32",
+                    choices=["float32", "bfloat16"],
+                    help="dtype of the learner copies (MAvgConfig."
+                         "compute_dtype); the meta state stays float32")
+    ap.add_argument("--mesh", default="none", choices=["none", "host"],
+                    help="host: the learners spread over every chip of "
+                         "this host, learner stack and batches sharded "
+                         "over 'data' (--learners must divide by the "
+                         "chip count)")
     ap.add_argument("--checkpoint-dir", default=None)
     ap.add_argument("--comm", default="dense", choices=COMM_SCHEMES,
                     help="meta-communication compression scheme (repro.comm)")
@@ -200,8 +217,14 @@ def main() -> None:
                     help="quarantine hysteresis: clean probation windows "
                          "a quarantined learner must sit out before "
                          "readmission (total mask = window * this)")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+def build(args):
+    """Everything a run needs from parsed ``args``.
+
+    Returns ``(cfg, loss_fn, make_trainer)``; ``make_trainer(plan)``
+    builds a fresh Trainer for a ``core.supervisor.RecoveryPlan``."""
     cfg = get_config(args.arch)
     if not args.full:
         cfg = cfg.reduced()
@@ -271,6 +294,10 @@ def main() -> None:
             algorithm=args.algorithm, num_learners=args.learners,
             k_steps=args.k, learner_lr=args.lr,
             momentum=args.momentum * momentum_scale,
+            compute_dtype=args.compute_dtype,
+            # without a mesh one device holds every learner: running them
+            # in turn keeps one learner's program and activations, not L
+            sequential_learners=args.mesh == "none",
             finite_guard=args.finite_guard,
             robust=robust,
             comm=CommConfig(scheme=args.comm, k_frac=args.comm_k_frac,
@@ -286,6 +313,26 @@ def main() -> None:
 
     def loss_fn(params, batch):
         return model_api.loss_fn(params, cfg, batch)
+
+    mesh = state_sh = None
+    batch_fn = lm_batch_fn(cfg, args.learners, args.k, args.batch, args.seq)
+    if args.mesh == "host":
+        from repro.launch.mesh import make_host_mesh
+        from repro.launch.specs import state_shardings
+
+        mesh = make_host_mesh()
+        n = mesh.shape["data"]
+        if args.learners % n:
+            raise SystemExit(
+                f"--mesh host: --learners {args.learners} does not divide "
+                f"over {n} chips"
+            )
+        state_sh = state_shardings(cfg, make_mcfg(), mesh)
+        batch_sh = NamedSharding(mesh, P("data"))
+        lm_batches = batch_fn
+        batch_fn = lambda rng, step: jax.device_put(
+            lm_batches(rng, step), batch_sh
+        )
 
     def make_trainer(plan) -> Trainer:
         tcfg = TrainConfig(
@@ -308,11 +355,22 @@ def main() -> None:
             tcfg,
             loss_fn,
             init_params_fn=lambda rng: model_api.init_params(rng, cfg),
-            batch_fn=lm_batch_fn(cfg, args.learners, args.k, args.batch,
-                                 args.seq),
+            batch_fn=batch_fn,
             lr_schedule=warmup_cosine(args.lr * plan.lr_scale, 5,
                                       args.steps),
+            mesh=mesh,
+            state_shardings=state_sh,
         )
+
+    return cfg, loss_fn, make_trainer
+
+
+def main(argv=None) -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    args = parse_args(argv)
+    enable_compile_cache()
+    cfg, loss_fn, make_trainer = build(args)
 
     if args.supervise:
         from repro.core.supervisor import (
@@ -353,7 +411,9 @@ def main() -> None:
             print(f"resumed from {ckpt}")
         history = trainer.run()
 
-    eval_batch = lm_eval_set(cfg, n=32, seq_len=args.seq)
+    # held out: one global batch of sequences (32 at the defaults)
+    eval_batch = lm_eval_set(cfg, n=args.learners * args.batch,
+                             seq_len=args.seq)
     loss, _ = jax.jit(loss_fn)(unpack_params(trainer.state), eval_batch)
     print(f"\nfinal train loss {history[-1]['loss']:.4f}  "
           f"eval loss {float(loss):.4f}  "

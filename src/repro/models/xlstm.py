@@ -1,9 +1,12 @@
 """xLSTM (arXiv:2405.04517): mLSTM (matrix-memory) + sLSTM (scalar-memory)
 blocks with exponential gating, alternating in a ``slstm_every`` pattern.
 
-Training uses the recurrent form via ``lax.scan`` over time (O(S) — this is
-what makes the long_500k shape runnable for this family); decode carries the
-per-layer recurrent state, so serving one token is O(1) in context length.
+The mLSTM runs in one of two equivalent forms, chosen from the sequence
+length: the chunkwise-parallel form when the length divides by
+``MLSTM_CHUNK`` (training and long prefills), the step-recurrent
+``lax.scan`` over time otherwise (decode, short or ragged lengths). Decode
+carries the per-layer recurrent state, so serving one token is O(1) in
+context length.
 
 State pytrees:
   mLSTM: C (B, nh, hd, hd) matrix memory, n (B, nh, hd), m (B, nh)
@@ -96,18 +99,14 @@ def _causal_conv(x, w):
     return out
 
 
-# Chunk length for the chunkwise-parallel mLSTM training form. 0 keeps the
-# step-recurrent form. Perf iteration (EXPERIMENTS.md section Perf,
-# xlstm-350m): the recurrent form round-trips the (B, nh, hd, hd) matrix
-# memory through HBM once per TOKEN; the chunkwise form (equivalent math,
-# xLSTM paper appendix) carries state once per CHUNK and turns the
-# intra-chunk work into MXU-shaped matmuls.
-MLSTM_CHUNK = 0
-
-
-def set_mlstm_chunk(n: int) -> None:
-    global MLSTM_CHUNK
-    MLSTM_CHUNK = n
+# Chunk length of the chunkwise-parallel mLSTM form, used for every
+# sequence whose length divides by it (0 = always the recurrent form). The
+# recurrent form keeps the (B, nh, hd, hd) matrix memory of EVERY token for
+# the backward pass — at xlstm-350m width (4, 4, 512, 512) f32 per token,
+# 48 GiB for one L=2, S=512 meta step, which no 16 GiB chip holds; the
+# chunkwise form (equivalent math, xLSTM paper appendix) keeps it once per
+# CHUNK and turns the intra-chunk work into MXU-shaped matmuls.
+MLSTM_CHUNK = 64
 
 
 def _mlstm_inputs(bp, cfg: ModelConfig, x, state):
@@ -218,10 +217,19 @@ def mlstm_chunked(bp, cfg: ModelConfig, x, state=None, chunk: int = 64):
 
 
 def mlstm_seq(bp, cfg: ModelConfig, x, state=None):
-    """x: (B, S, d). Returns (out (B, S, d), final state)."""
-    B, S, d = x.shape
-    if MLSTM_CHUNK and S % MLSTM_CHUNK == 0 and S > 1:
+    """x: (B, S, d). Returns (out (B, S, d), final state).
+
+    The chunkwise form when S divides by ``MLSTM_CHUNK``, else the
+    step-recurrent form."""
+    S = x.shape[1]
+    if MLSTM_CHUNK and S % MLSTM_CHUNK == 0:
         return mlstm_chunked(bp, cfg, x, state, chunk=MLSTM_CHUNK)
+    return mlstm_recurrent(bp, cfg, x, state)
+
+
+def mlstm_recurrent(bp, cfg: ModelConfig, x, state=None):
+    """The step-recurrent mLSTM: one ``lax.scan`` step per token."""
+    B, S, d = x.shape
     d_in = cfg.ssm_expand * d
     nh = cfg.num_heads
     hd = d_in // nh

@@ -256,9 +256,9 @@ def profile_phases(loss_fn, cfg, state, batches, lr=None, *, iters: int = 10,
         from repro.obs.trace import Tracer
 
         t = Tracer(enabled=True)
-        if t.profiler_start(profiler_trace_dir):
-            try:
-                jax.block_until_ready(jax.jit(whole_step)(state, batches, lr))
-            finally:
-                t.profiler_stop()
+        t.profiler_start(profiler_trace_dir)
+        try:
+            jax.block_until_ready(jax.jit(whole_step)(state, batches, lr))
+        finally:
+            t.profiler_stop()
     return rows

@@ -143,19 +143,15 @@ class Tracer:
         return path
 
     # ------------------------------------------------------------------
-    def profiler_start(self, trace_dir: str) -> bool:
+    def profiler_start(self, trace_dir: str) -> None:
         """Start a jax device profile into ``trace_dir`` (TensorBoard /
-        xplane format, includes its own Chrome trace). Best-effort: some
-        builds lack profiler support — returns False instead of raising
-        so telemetry never kills a run."""
-        try:
-            import jax
+        xplane format, includes its own Chrome trace). A profile that was
+        asked for and cannot start raises: a run that silently produced
+        no trace would read as a traced one."""
+        import jax
 
-            jax.profiler.start_trace(trace_dir)
-            self._profiling = True
-            return True
-        except Exception:
-            return False
+        jax.profiler.start_trace(trace_dir)
+        self._profiling = True
 
     def profiler_stop(self) -> None:
         if not self._profiling:

@@ -1,4 +1,4 @@
-"""Three-term roofline model for TPU v5e (target hardware).
+"""Three-term roofline model, per chip kind (target hardware: TPU v5e).
 
     compute    = HLO_FLOPs        / (chips x peak_FLOP/s)
     memory     = HLO_bytes        / (chips x HBM_bw)
@@ -23,11 +23,43 @@ from typing import Optional
 
 from repro.configs.base import CommConfig, InputShape, ModelConfig
 
-# TPU v5e per-chip constants (from the spec)
-PEAK_FLOPS_BF16 = 197e12  # FLOP/s
-HBM_BW = 819e9  # B/s
-ICI_LINK_BW = 50e9  # B/s per link (fast intra-node edge class)
-DCN_LINK_BW = 25e9  # B/s per host (slow inter-node edge class, ~200 Gb/s)
+# Per-chip peaks keyed by ``jax.devices()[i].device_kind``, each with its
+# source. A device kind missing here is an error (``device_peaks``), never
+# a default.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {  # TPU v5e
+        "peak_flops_bf16": 197e12,  # FLOP/s
+        "hbm_bw": 819e9,  # B/s
+        # fast intra-node edge class: 1,600 Gbit/s of ICI per chip over
+        # 4 links
+        "ici_link_bw": 50e9,  # B/s per link
+        # slow inter-node edge class
+        "dcn_link_bw": 25e9,  # B/s per host
+        "source": "Google Cloud documentation, 'TPU v5e': 197 TFLOP/s "
+                  "bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI per "
+                  "chip; DCN 200 Gbit/s per host is a modeling assumption",
+    },
+}
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
+
+def device_peaks(device_kind: str) -> dict:
+    """The peak table entry of one chip kind; raises for an unknown one."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no roofline peaks for device kind {device_kind!r}; known: "
+            f"{sorted(DEVICE_PEAKS)}"
+        ) from None
+
+
+# the target chip's peaks, for the modeled (no-device) tables
+_TARGET = device_peaks(TARGET_DEVICE_KIND)
+PEAK_FLOPS_BF16 = _TARGET["peak_flops_bf16"]
+HBM_BW = _TARGET["hbm_bw"]
+ICI_LINK_BW = _TARGET["ici_link_bw"]
+DCN_LINK_BW = _TARGET["dcn_link_bw"]
 
 
 @dataclass
@@ -212,16 +244,19 @@ def compute_terms(*, arch: str, shape: InputShape, mesh_name: str, chips: int,
                   hlo_flops: float, hlo_bytes: float, collective_bytes: float,
                   cfg: ModelConfig, k_steps: int = 1,
                   per_device: bool = True, comm: Optional[CommConfig] = None,
-                  num_learners: int = 1, topology=None) -> RooflineTerms:
+                  num_learners: int = 1, topology=None,
+                  device_kind: str = TARGET_DEVICE_KIND) -> RooflineTerms:
     """per_device=True: the HLO numbers come from the SPMD-partitioned
     module, i.e. they are already per-chip (this is what
     ``compiled.as_text()`` exposes). The spec formula X/(chips*rate) with
-    whole-program X is identical to X_per_device/rate."""
+    whole-program X is identical to X_per_device/rate. The rates are
+    ``device_kind``'s row of ``DEVICE_PEAKS``."""
+    pk = device_peaks(device_kind)
     mf = model_flops(cfg, shape, k_steps)
     div = 1 if per_device else chips
-    compute_s = hlo_flops / (div * PEAK_FLOPS_BF16)
-    memory_s = hlo_bytes / (div * HBM_BW)
-    collective_s = collective_bytes / (div * ICI_LINK_BW)
+    compute_s = hlo_flops / (div * pk["peak_flops_bf16"])
+    memory_s = hlo_bytes / (div * pk["hbm_bw"])
+    collective_s = collective_bytes / (div * pk["ici_link_bw"])
     terms = {"compute": compute_s, "memory": memory_s, "collective": collective_s}
     bottleneck = max(terms, key=terms.get)
     mf_dev = mf / chips if per_device else mf
@@ -233,8 +268,8 @@ def compute_terms(*, arch: str, shape: InputShape, mesh_name: str, chips: int,
         intra_b, inter_b = edge["intra_bytes"], edge["inter_bytes"]
         wire_bytes = edge["total_bytes"]
         # each edge class rides its own fabric
-        wire_s = (intra_b / (chips * ICI_LINK_BW)
-                  + inter_b / (chips * DCN_LINK_BW))
+        wire_s = (intra_b / (chips * pk["ici_link_bw"])
+                  + inter_b / (chips * pk["dcn_link_bw"]))
     return RooflineTerms(
         arch=arch,
         shape=shape.name,

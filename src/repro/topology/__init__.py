@@ -36,7 +36,15 @@ def make_topology(cfg, reducer=None) -> Topology:
     ``reducer`` overrides the primary reducer (flat: the all-reduce;
     hierarchical: intra-group; gossip: neighbor exchange) — the same
     injection point meta_step/make_meta_step always exposed.
+
+    An unset ``cfg.use_pallas`` is resolved from the platform here, once,
+    so every topology and robust hook it builds sees a plain bool.
     """
+    from dataclasses import replace
+
+    from repro.kernels.ops import resolve_use_pallas
+
+    cfg = replace(cfg, use_pallas=resolve_use_pallas(cfg.use_pallas))
     kind = cfg.topology.kind
     # the legacy downpour/eamsgd algorithms are aliases onto the async
     # bounded-staleness server (resolve_async_config) — core/meta.py has

@@ -23,10 +23,13 @@ construction instead of per meta_step call.
 """
 from __future__ import annotations
 
+import math
+from functools import partial
 from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import MAvgConfig
 # the packed-plane dispatch predicate lives with the kernels it routes to
@@ -89,13 +92,31 @@ def fused_momentum_broadcast_update(gp, v, avg, *, mu, eta, num_learners,
     ``block_momentum_update`` followed by cast + broadcast.
 
     Returns (gp', v', learners).
+
+    Under a device mesh in context (``jax.set_mesh``, as the Trainer sets
+    it for a sharded run) the Pallas kernel runs inside ``shard_map``:
+    XLA cannot partition a Mosaic kernel. Each device updates the whole
+    (replicated) meta plane and emits only the reset planes of the
+    learners it holds on the mesh's learner axes.
     """
     from repro.kernels import ops as kops
 
-    return kops.fused_momentum_broadcast(
-        gp, v, avg, mu=mu, eta=eta, num_learners=num_learners,
-        ldtype=ldtype, nesterov=nesterov, use_pallas=use_pallas,
+    update = partial(
+        kops.fused_momentum_broadcast, mu=mu, eta=eta, ldtype=ldtype,
+        nesterov=nesterov, use_pallas=use_pallas,
     )
+    mesh = jax.sharding.get_abstract_mesh()
+    if not use_pallas or mesh.empty or mesh.size == 1:
+        return update(gp, v, avg, num_learners=num_learners)
+    from repro.launch.mesh import learner_axes
+
+    axes = learner_axes(mesh)
+    per_device = num_learners // math.prod(mesh.shape[a] for a in axes)
+    return jax.shard_map(
+        partial(update, num_learners=per_device), mesh=mesh,
+        in_specs=(P(), P(), P()), out_specs=(P(), P(), P(axes)),
+        check_vma=False,
+    )(gp, v, avg)
 
 
 class Topology:

@@ -24,7 +24,8 @@ cfg = dataclasses.replace(get_config("deepseek-moe-16b").reduced(),
 p = moe.init_moe(jax.random.PRNGKey(0), cfg)
 x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, cfg.d_model)) * 0.5
 out_ref, aux_ref = moe.moe_layer(x, p, cfg)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_debug_mesh
+mesh = make_debug_mesh(2, 4)
 moe.set_expert_axis("model", mesh)
 with mesh:
     out_sm, aux_sm = jax.jit(lambda x, p: moe.moe_layer(x, p, cfg))(x, p)

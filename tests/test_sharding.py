@@ -9,8 +9,7 @@ from repro.launch import specs as S
 from repro.models import api as model_api
 from repro.sharding import add_learner_axis, make_param_specs
 
-# jax >= 0.4.35: AbstractMesh takes a single ((name, size), ...) tuple
-MESH = AbstractMesh((("data", 16), ("model", 16)))
+MESH = AbstractMesh((16, 16), ("data", "model"))
 
 
 def _specs(arch, **kw):

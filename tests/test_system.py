@@ -74,16 +74,19 @@ from repro.models import api as model_api
 from repro.launch import specs as S
 
 use_mesh = sys.argv[1] == "mesh"
+use_pallas = len(sys.argv) > 2 and sys.argv[2] == "pallas"
 cfg = get_config("qwen3-1.7b").reduced()
 mcfg = MAvgConfig(algorithm="mavg", num_learners=4, k_steps=2,
-                  learner_lr=0.5, momentum=0.6)
+                  learner_lr=0.5, momentum=0.6, use_pallas=use_pallas)
 params = model_api.init_params(jax.random.PRNGKey(0), cfg)
 state = init_state(params, mcfg)
 loss_fn = lambda p, b: model_api.loss_fn(p, cfg, b)
 step_fn = make_meta_step(loss_fn, mcfg)
 if use_mesh:
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
-    with mesh:
+    from repro.launch.mesh import make_debug_mesh
+    mesh = make_debug_mesh(4, 2)
+    # the mesh in context: the Pallas meta kernel runs under shard_map
+    with jax.set_mesh(mesh):
         sh = S.state_shardings(cfg, mcfg, mesh)
         bsh = {k: NamedSharding(mesh, P("data")) for k in ("tokens", "labels")}
         step = jax.jit(step_fn, in_shardings=(sh, bsh), out_shardings=(sh, None))
@@ -91,7 +94,7 @@ if use_mesh:
         losses = []
         for i in range(4):
             b = bf(jax.random.fold_in(jax.random.PRNGKey(123), i), i)
-            state, m = step(state, b)
+            state, m = step(state, jax.device_put(b, bsh))
             losses.append(float(m["loss"]))
 else:
     step = jax.jit(step_fn)
@@ -105,21 +108,30 @@ print(json.dumps(losses))
 """
 
 
-def test_meta_step_under_real_mesh(tmp_path):
-    """Same program, 8 sharded host devices vs 1: losses must agree."""
+def _run_mesh_script(tmp_path, *argv):
     script = tmp_path / "mesh_run.py"
     script.write_text(_MESH_SCRIPT)
     env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+    out = subprocess.run(
+        [sys.executable, str(script), *argv], env=env, capture_output=True,
+        text=True, timeout=1200,
+    )
+    assert out.returncode == 0, out.stderr[-3000:]
+    return json.loads(out.stdout.strip().splitlines()[-1])
 
-    def run(mode):
-        out = subprocess.run(
-            [sys.executable, str(script), mode], env=env, capture_output=True,
-            text=True, timeout=1200,
-        )
-        assert out.returncode == 0, out.stderr[-3000:]
-        return json.loads(out.stdout.strip().splitlines()[-1])
 
-    losses_mesh = run("mesh")
-    losses_single = run("single")
+def test_meta_step_under_real_mesh(tmp_path):
+    """Same program, 8 sharded host devices vs 1: losses must agree."""
+    losses_mesh = _run_mesh_script(tmp_path, "mesh")
+    losses_single = _run_mesh_script(tmp_path, "single")
+    for a, b in zip(losses_mesh, losses_single):
+        assert abs(a - b) < 5e-2, (losses_mesh, losses_single)
+
+
+def test_meta_kernel_under_real_mesh(tmp_path):
+    """The Pallas meta kernel under the mesh (shard_map, interpret mode
+    here) against the jnp meta update on one device: losses must agree."""
+    losses_mesh = _run_mesh_script(tmp_path, "mesh", "pallas")
+    losses_single = _run_mesh_script(tmp_path, "single")
     for a, b in zip(losses_mesh, losses_single):
         assert abs(a - b) < 5e-2, (losses_mesh, losses_single)
