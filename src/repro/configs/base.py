@@ -666,12 +666,14 @@ class ObsConfig:
                      trace exports; required for the file sinks
     buffer_capacity  rows of the device metric ring (0 -> sized to
                      max(log_every, 1), the flush cadence)
-    trace            phase span timers (dispatch / host_flush /
-                     checkpoint_io / sink) + Chrome-trace export to
+    trace            phase span timers over every host step of the run
+                     (run / meta_step / batch / lr / dispatch / host_flush
+                     / checkpoint_io / sink) + Chrome-trace export to
                      ``run_dir/trace.json`` at the end of each run
     profiler         capture a jax.profiler device trace of the run into
-                     ``run_dir/jax_trace`` (best-effort; needs profiler
-                     support in the jax build)
+                     ``run_dir/jax_trace`` (raises where no trace can
+                     start); with ``trace`` on, trace.json counts from
+                     the profile's start
     cost_analysis    record the compiled meta step's measured HBM /
                      peak-state / flops numbers (roofline.hlo_cost
                      .jit_cost) into the run manifest — one extra AOT

@@ -201,6 +201,9 @@ def _local_phase(loss_fn: LossFn, learners, local_mom, batches, cfg: MAvgConfig,
         repack = lambda t: None if t is None else spec.pack(t, dtype=ldt)
     else:
         unpack = repack = lambda t: t
+    # the learner's own update work, apart from the model's forward and
+    # backward: grad norm, SGD step, and the unpack/repack of its planes
+    update_scope = partial(jax.named_scope, "obs.learner_update")
 
     def sgd_update(w, mom, g):
         # update math in f32, stored back in the learner dtype (bf16
@@ -229,13 +232,17 @@ def _local_phase(loss_fn: LossFn, learners, local_mom, batches, cfg: MAvgConfig,
         def step(carry, b):
             w, mom = carry
             (loss, _aux), g = jax.value_and_grad(loss_fn, has_aux=True)(w, b)
-            gnorm = tree_norm(g)
-            w, mom = sgd_update(w, mom, g)
+            with update_scope():
+                gnorm = tree_norm(g)
+                w, mom = sgd_update(w, mom, g)
             return (w, mom), (loss, gnorm)
 
-        w, mom = unpack(w), unpack(mom)
+        with update_scope():
+            w, mom = unpack(w), unpack(mom)
         (w, mom), (losses, gnorms) = lax.scan(step, (w, mom), bks)
-        return repack(w), repack(mom), losses.mean(), gnorms.mean()
+        with update_scope():
+            w, mom = repack(w), repack(mom)
+        return w, mom, losses.mean(), gnorms.mean()
 
     def one_learner_masked(w, mom, bks, s):
         k = jax.tree.leaves(bks)[0].shape[0]
@@ -244,18 +251,24 @@ def _local_phase(loss_fn: LossFn, learners, local_mom, batches, cfg: MAvgConfig,
             w, mom = carry
             b, i = xs
             (loss, _aux), g = jax.value_and_grad(loss_fn, has_aux=True)(w, b)
-            gnorm = tree_norm(g)
-            w_upd, mom_upd = sgd_update(w, mom, g)
-            keep = i < s
-            w = jax.tree.map(lambda n, o: jnp.where(keep, n, o), w_upd, w)
-            mom = jax.tree.map(lambda n, o: jnp.where(keep, n, o), mom_upd, mom)
+            with update_scope():
+                gnorm = tree_norm(g)
+                w_upd, mom_upd = sgd_update(w, mom, g)
+                keep = i < s
+                w = jax.tree.map(lambda n, o: jnp.where(keep, n, o), w_upd, w)
+                mom = jax.tree.map(
+                    lambda n, o: jnp.where(keep, n, o), mom_upd, mom
+                )
             return (w, mom), (loss, gnorm, keep.astype(jnp.float32))
 
-        w, mom = unpack(w), unpack(mom)
+        with update_scope():
+            w, mom = unpack(w), unpack(mom)
         (w, mom), (losses, gnorms, act) = lax.scan(
             step, (w, mom), (bks, jnp.arange(k))
         )
-        return (repack(w), repack(mom),
+        with update_scope():
+            w, mom = repack(w), repack(mom)
+        return (w, mom,
                 (losses * act).sum(), (gnorms * act).sum(), act.sum())
 
     # without learner-level momentum the carry holds no momentum stack at

@@ -293,7 +293,6 @@ class Trainer:
         """
         n = meta_steps if meta_steps is not None else self.cfg.meta_steps
         run_t0 = time.time()
-        start = int(self.state.step)  # the only pre-loop host sync
         self._last_flush_t = run_t0
         # samples per completed K-step block; the topology says how many
         # blocks have completed through a given meta step (async learners
@@ -370,55 +369,61 @@ class Trainer:
         )
         with self.tracer.session(export_path, profiler_dir), \
                 self._mesh_context():
+            with self.tracer.span("obs.step_read"):
+                start = int(self.state.step)  # the only pre-loop host sync
             try:
                 for i in range(n):
                     step = start + i
-                    rng = jax.random.fold_in(self.data_rng, step)
-                    batches = self.batch_fn(rng, step)
-                    lr = (
-                        self.lr_schedule(step)
-                        if self.lr_schedule
-                        else jnp.float32(self.mcfg.learner_lr)
-                    )
-                    if self._mb is None:
-                        self._init_obs(batches, lr)
-                    if self._mb.full:  # ring smaller than the log window
-                        flush()
-                        maybe_halt(step - 1)
-                    with self.tracer.span("obs.dispatch"):
-                        self.state, ring = self._fused(
-                            self.state, batches, lr,
-                            self._mb.buf, self._mb.row_index(),
-                        )
-                    self._mb.note(step, ring)
-                    if log and (step % self.cfg.log_every == 0):
-                        flush()
-                        maybe_halt(step)
-                        m = self.history[-1]
-                        log(
-                            f"[{self.mcfg.algorithm}] meta_step={step} "
-                            f"loss={m['loss']:.4f} "
-                            f"gnorm={m.get('grad_norm', 0):.3f} "
-                            f"{m['meta_steps_per_sec']:.2f} steps/s "
-                            f"{m['samples_per_sec']:.0f} samples/s "
-                            f"({time.time() - run_t0:.1f}s)"
-                        )
-                    if (
-                        self.cfg.checkpoint_dir
-                        and self.cfg.checkpoint_every
-                        and (step + 1) % self.cfg.checkpoint_every == 0
-                    ):
-                        fault = (
-                            self._chaos_schedule.save_fault(step + 1)
-                            if self._chaos_schedule is not None else None
-                        )
-                        with self.tracer.span("obs.checkpoint_io"):
-                            save_state(
-                                self.cfg.checkpoint_dir, self.state, step + 1,
-                                manifest=self.manifest,
-                                keep=self.cfg.checkpoint_keep,
-                                fault=fault,
+                    with self.tracer.step(step):
+                        with self.tracer.span("obs.batch"):
+                            rng = jax.random.fold_in(self.data_rng, step)
+                            batches = self.batch_fn(rng, step)
+                        with self.tracer.span("obs.lr"):
+                            lr = (
+                                self.lr_schedule(step)
+                                if self.lr_schedule
+                                else jnp.float32(self.mcfg.learner_lr)
                             )
+                        if self._mb is None:
+                            self._init_obs(batches, lr)
+                        if self._mb.full:  # ring smaller than the log window
+                            flush()
+                            maybe_halt(step - 1)
+                        with self.tracer.span("obs.dispatch"):
+                            self.state, ring = self._fused(
+                                self.state, batches, lr,
+                                self._mb.buf, self._mb.row_index(),
+                            )
+                        self._mb.note(step, ring)
+                        if log and (step % self.cfg.log_every == 0):
+                            flush()
+                            maybe_halt(step)
+                            m = self.history[-1]
+                            log(
+                                f"[{self.mcfg.algorithm}] meta_step={step} "
+                                f"loss={m['loss']:.4f} "
+                                f"gnorm={m.get('grad_norm', 0):.3f} "
+                                f"{m['meta_steps_per_sec']:.2f} steps/s "
+                                f"{m['samples_per_sec']:.0f} samples/s "
+                                f"({time.time() - run_t0:.1f}s)"
+                            )
+                        if (
+                            self.cfg.checkpoint_dir
+                            and self.cfg.checkpoint_every
+                            and (step + 1) % self.cfg.checkpoint_every == 0
+                        ):
+                            fault = (
+                                self._chaos_schedule.save_fault(step + 1)
+                                if self._chaos_schedule is not None else None
+                            )
+                            with self.tracer.span("obs.checkpoint_io"):
+                                save_state(
+                                    self.cfg.checkpoint_dir, self.state,
+                                    step + 1,
+                                    manifest=self.manifest,
+                                    keep=self.cfg.checkpoint_keep,
+                                    fault=fault,
+                                )
                 flush()  # the final (possibly partial) log window
                 maybe_halt(start + n - 1)
             finally:

@@ -318,10 +318,14 @@ def init_embed(key, cfg: ModelConfig) -> dict:
     return p
 
 
+# the work over the vocabulary runs under the scope "obs.head", so a
+# profile tells it from the blocks'
+@jax.named_scope("obs.head")
 def embed_tokens(p, cfg: ModelConfig, tokens):
     return p["embedding"].astype(jnp.dtype(cfg.dtype))[tokens]
 
 
+@jax.named_scope("obs.head")
 def lm_head(p, cfg: ModelConfig, x):
     if cfg.tie_embeddings:
         w = p["embedding"].T
@@ -330,6 +334,7 @@ def lm_head(p, cfg: ModelConfig, x):
     return jnp.einsum("bsd,dv->bsv", x, w.astype(x.dtype)).astype(jnp.float32)
 
 
+@jax.named_scope("obs.head")
 def cross_entropy(logits, labels, mask=None):
     """Mean token cross-entropy. labels: int32, -1 entries ignored."""
     valid = labels >= 0

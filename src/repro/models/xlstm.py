@@ -365,11 +365,13 @@ def _scan_groups(params, cfg: ModelConfig, x, states=None):
 
         def mstep(x, minp):
             bp, st = minp
-            x, st = mlstm_seq(bp, cfg, x, st)
+            with jax.named_scope("obs.mlstm"):
+                x, st = mlstm_seq(bp, cfg, x, st)
             return x, st
 
         x, ms = lax.scan(mstep, x, (mp, ms))
-        x, ss = slstm_seq(sp, cfg, x, ss)
+        with jax.named_scope("obs.slstm"):
+            x, ss = slstm_seq(sp, cfg, x, ss)
         return x, (ms, ss)
 
     x, (m_state, s_state) = lax.scan(
@@ -381,13 +383,15 @@ def _scan_groups(params, cfg: ModelConfig, x, states=None):
 def forward(params, cfg: ModelConfig, batch, *, use_pallas: bool = False):
     x = L.embed_tokens(params["embed"], cfg, batch["tokens"])
     x, _ = _scan_groups(params, cfg, x)
-    x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
+    with jax.named_scope("obs.head"):
+        x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
     return L.lm_head(params["embed"], cfg, x), {"aux_loss": jnp.float32(0.0)}
 
 
 def loss_fn(params, cfg: ModelConfig, batch, *, use_pallas: bool = False):
     logits, _ = forward(params, cfg, batch)
-    ce = L.cross_entropy(logits[:, :-1], batch["labels"][:, 1:])
+    with jax.named_scope("obs.head"):  # the shift of the logits too
+        ce = L.cross_entropy(logits[:, :-1], batch["labels"][:, 1:])
     return ce, {"ce": ce, "aux_loss": jnp.float32(0.0)}
 
 

@@ -1,14 +1,23 @@
 """Phase tracing: config-gated span timers with Chrome-trace export.
 
-The training loop has four host-visible phases worth timing — step
-dispatch (local phase + meta mix enqueue), host flush (the one sync per
-``log_every`` window), checkpoint I/O, and sink writes. ``Tracer.span``
-wraps each in a wall-clock timer plus a ``jax.profiler.TraceAnnotation``
+Every host step of the training loop is timed: each iteration is one
+``obs.meta_step`` span (``Tracer.step``) holding the batch draw
+(``obs.batch``), the learning-rate schedule (``obs.lr``), step dispatch
+(``obs.dispatch``: local phase + meta mix enqueue), the host flush
+(``obs.host_flush``, the one sync per ``log_every`` window), sink writes
+(``obs.sink_append``) and checkpoint I/O (``obs.checkpoint_io``); the
+run's one step-counter read before the loop is ``obs.step_read``, and
+the whole run, these and the final flush, is ``obs.run``.
+``Tracer.span`` wraps each in a wall-clock timer plus a
+``jax.profiler.TraceAnnotation`` (``StepTraceAnnotation`` for the step)
 so the spans also show up inside a device profile when one is being
 captured (``profiler_start``/``profiler_stop`` drive
-``jax.profiler.start_trace`` around the run; the on-device split of
-local phase vs meta mix comes from the ``jax.named_scope`` annotations
-in ``core.meta.meta_step``, which label the HLO itself).
+``jax.profiler.start_trace`` around the run). The on-device split comes
+from ``jax.named_scope`` annotations, which label the HLO itself:
+``obs.local_phase`` and ``obs.meta_mix`` in ``core.meta.meta_step``,
+inside the local phase ``obs.learner_update`` (core/meta.py),
+``obs.mlstm`` and ``obs.slstm`` (models/xlstm.py) and ``obs.head``
+(models/layers.py).
 
 Disabled tracers cost one predicate per span — safe to leave in hot
 paths. ``export_chrome_trace`` writes the collected spans in the Chrome
@@ -45,23 +54,34 @@ class Tracer:
         self.interrupted: list[str] = []  # names closed abnormally
 
     @contextmanager
-    def span(self, name: str):
-        """Time a phase; no-op (one branch) when disabled."""
+    def span(self, name: str, step_num: int | None = None):
+        """Time a phase; no-op (one branch) when disabled. With
+        ``step_num`` the span marks that step in a device profile."""
         if not self.enabled:
             yield
             return
         import jax
 
+        annotation = (
+            jax.profiler.TraceAnnotation(name) if step_num is None
+            else jax.profiler.StepTraceAnnotation(name, step_num=step_num)
+        )
         t0 = time.perf_counter()
         entry = (name, t0)
         self._open.append(entry)
         try:
-            with jax.profiler.TraceAnnotation(name):
+            with annotation:
                 yield
         finally:
             if entry in self._open:
                 self._open.remove(entry)
             self.events.append((name, t0 - self._t0, time.perf_counter() - t0))
+
+    def step(self, step: int):
+        """The span ``obs.meta_step`` around one iteration of the training
+        loop, marked as step ``step`` in a device profile; no-op (one
+        branch) when disabled."""
+        return self.span("obs.meta_step", step_num=step)
 
     def close_open_spans(self) -> list[str]:
         """Finalize every still-open span at the current wall clock.
@@ -82,7 +102,8 @@ class Tracer:
     @contextmanager
     def session(self, export_path: str | None = None,
                 profiler_dir: str | None = None):
-        """Exception-safe tracing scope around a whole run.
+        """Exception-safe tracing scope around a whole run, itself the
+        span ``obs.run``.
 
         Enter: optionally starts a device profile into ``profiler_dir``.
         Exit — ALWAYS, crash included: closes open spans, stops the
@@ -95,7 +116,8 @@ class Tracer:
             self.profiler_start(profiler_dir)
         ok = False
         try:
-            yield self
+            with self.span("obs.run"):
+                yield self
             ok = True
         finally:
             self.close_open_spans()
@@ -108,21 +130,10 @@ class Tracer:
                         raise
 
     # ------------------------------------------------------------------
-    def summary(self) -> dict:
-        """{phase: {count, total_s, mean_s}} over all recorded spans."""
-        out: dict[str, dict] = {}
-        for name, _t, dur in self.events:
-            s = out.setdefault(name, {"count": 0, "total_s": 0.0})
-            s["count"] += 1
-            s["total_s"] += dur
-        for s in out.values():
-            s["mean_s"] = s["total_s"] / s["count"]
-        return out
-
     def export_chrome_trace(self, path: str) -> str:
         """Write spans as Chrome-trace JSON (load in chrome://tracing or
         https://ui.perfetto.dev). Timestamps in microseconds since the
-        tracer was created."""
+        tracer was created, or since the device profile it started."""
         d = os.path.dirname(path)
         if d:
             os.makedirs(d, exist_ok=True)
@@ -147,10 +158,20 @@ class Tracer:
         """Start a jax device profile into ``trace_dir`` (TensorBoard /
         xplane format, includes its own Chrome trace). A profile that was
         asked for and cannot start raises: a run that silently produced
-        no trace would read as a traced one."""
+        no trace would read as a traced one.
+
+        The spans' clock is moved to the profile's origin, which the
+        profiler sets as ``start_trace`` returns (its first call in a
+        process first spends tens of ms setting up), so ``trace.json``
+        and the profile lie side by side; spans already recorded are
+        shifted onto the new clock."""
         import jax
 
         jax.profiler.start_trace(trace_dir)
+        origin = time.perf_counter()
+        self.events = [(name, t0 + self._t0 - origin, dur)
+                       for name, t0, dur in self.events]
+        self._t0 = origin
         self._profiling = True
 
     def profiler_stop(self) -> None:
