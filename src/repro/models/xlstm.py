@@ -344,7 +344,12 @@ def slstm_init_state(cfg: ModelConfig, B: int):
 
 
 def _scan_groups(params, cfg: ModelConfig, x, states=None):
-    """Scan over super-blocks of (slstm_every-1) mLSTM + 1 sLSTM."""
+    """Scan over super-blocks of (slstm_every-1) mLSTM + 1 sLSTM.
+
+    Each block is its own checkpoint: the backward keeps only the blocks'
+    inputs and recomputes one block at a time, so no block's residuals
+    are stacked across the inner scan over a super-block's mLSTM blocks.
+    """
     B = x.shape[0]
     G = cfg.num_layers // cfg.slstm_every
     M = cfg.slstm_every - 1
@@ -359,19 +364,24 @@ def _scan_groups(params, cfg: ModelConfig, x, states=None):
     else:
         m_state, s_state = states
 
-    @partial(jax.checkpoint, policy=jax.checkpoint_policies.nothing_saveable)
+    remat = partial(jax.checkpoint, policy=jax.checkpoint_policies.nothing_saveable)
+
+    @remat
+    def mstep(x, minp):
+        bp, st = minp
+        with jax.named_scope("obs.mlstm"):
+            x, st = mlstm_seq(bp, cfg, x, st)
+        return x, st
+
+    @remat
+    def sblock(x, sp, ss):
+        with jax.named_scope("obs.slstm"):
+            return slstm_seq(sp, cfg, x, ss)
+
     def group(x, inp):
         mp, sp, ms, ss = inp
-
-        def mstep(x, minp):
-            bp, st = minp
-            with jax.named_scope("obs.mlstm"):
-                x, st = mlstm_seq(bp, cfg, x, st)
-            return x, st
-
         x, ms = lax.scan(mstep, x, (mp, ms))
-        with jax.named_scope("obs.slstm"):
-            x, ss = slstm_seq(sp, cfg, x, ss)
+        x, ss = sblock(x, sp, ss)
         return x, (ms, ss)
 
     x, (m_state, s_state) = lax.scan(

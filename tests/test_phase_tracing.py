@@ -2,8 +2,8 @@
 
   PT1  the compiled xlstm step names its sub-layers: every matrix product
        of the local phase lies under one of ``obs.mlstm``, ``obs.slstm``,
-       ``obs.head`` or ``obs.learner_update``, and the forward that the
-       group checkpoint recomputes is marked ``rematted_computation``.
+       ``obs.head`` or ``obs.learner_update``, and the forward that each
+       block's checkpoint recomputes is marked ``rematted_computation``.
   PT2  the learner update, and the unpack/repack of the learner planes,
        are ``obs.learner_update`` on the masked and unmasked paths alike.
   PT3  every host step of ``Trainer.run`` is a span: ``obs.batch``,
