@@ -26,18 +26,29 @@ from repro.kernels import pack_update as _pu
 from repro.kernels import quantize as _q
 from repro.kernels import ref as _ref
 from repro.kernels import robust_reduce as _rr
+from repro.kernels import slstm as _sl
 
 LANES = 128
 
 
+def _platform() -> str:
+    """The platform that work traced now runs on: the device of a
+    ``jax.default_device`` context (a CPU reference on a TPU host), else
+    the default backend."""
+    dev = jax.config.jax_default_device
+    if dev is None:
+        return jax.default_backend()
+    return dev if isinstance(dev, str) else dev.platform
+
+
 def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return _platform() != "tpu"
 
 
 def resolve_use_pallas(flag) -> bool:
     """A config's ``use_pallas``: an explicit True/False is kept; None
     means "compiled kernels on TPU, the jnp oracle elsewhere"."""
-    return jax.default_backend() == "tpu" if flag is None else bool(flag)
+    return _platform() == "tpu" if flag is None else bool(flag)
 
 
 # ---------------------------------------------------------------------------
@@ -387,3 +398,33 @@ def flash_attention(q, k, v, *, causal=True, sliding_window=0,
     )
     out = out.reshape(B, H, S, d_pad).transpose(0, 2, 1, 3)[..., :D]
     return out
+
+
+# ---------------------------------------------------------------------------
+# sLSTM time loop (models/xlstm.py slstm_seq)
+# ---------------------------------------------------------------------------
+
+
+def slstm_kernel_applies(seq_len: int, batch: int, d: int,
+                         head_dim: int) -> bool:
+    """Whether ``slstm_seq`` runs its time loop as the Pallas kernel pair
+    (kernels/slstm.py) rather than as a ``lax.scan``: on a TPU, for a
+    shape the kernels' tiling takes, at the default matmul precision (the
+    kernels' product is the default's one bf16 pass) and outside a
+    multi-device mesh (XLA cannot partition a Mosaic kernel)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return (resolve_use_pallas(None)
+            and jax.config.jax_default_matmul_precision is None
+            and (mesh.empty or mesh.size == 1)
+            and _sl.fits(seq_len, batch, d, head_dim))
+
+
+def slstm_recurrence(g, rs, state, *, time_block=_sl.TIME_BLOCK,
+                     interpret=None):
+    """The sLSTM recurrence as the kernel pair (differentiable).
+
+    g: (S, B, 4 d) f32 gate pre-activations, head-major then gate;
+    rs: (r_i, r_f, r_z, r_o), each (nh, hd, hd); state: (c, n, h, m),
+    each (B, d) f32. Returns (hs (S, B, d), final (c, n, h, m))."""
+    interpret = _default_interpret() if interpret is None else interpret
+    return _sl.recurrence(g, tuple(rs), tuple(state), time_block, interpret)

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.configs.base import ModelConfig
+from repro.kernels import ops as kops
 from repro.models import layers as L
 from repro.models.layers import _dense_init
 
@@ -286,6 +287,12 @@ def mlstm_init_state(cfg: ModelConfig, B: int):
 
 
 def slstm_seq(bp, cfg: ModelConfig, x, state=None):
+    """x: (B, S, d). Returns (out (B, S, d), final state).
+
+    The time loop runs as the Pallas kernel pair of kernels/slstm.py
+    where ``kernels.ops.slstm_kernel_applies`` takes the platform and
+    shape (TPU, whole time blocks), else as a ``lax.scan`` over time
+    (the CPU, decode's single step, other lengths)."""
     B, S, d = x.shape
     nh = cfg.num_heads
     hd = d // nh
@@ -300,12 +307,30 @@ def slstm_seq(bp, cfg: ModelConfig, x, state=None):
     }
     if state is None:
         state = slstm_init_state(cfg, B)
+    rs = {g: bp[f"r_{g}"] for g in ("i", "f", "z", "o")}
+    loop = (_slstm_kernel if kops.slstm_kernel_applies(S, B, d, hd)
+            else _slstm_scan)
+    hs, state = loop(pre, rs, state)
+    h = hs.reshape(B, S, d).astype(dt)
+    h = L.rmsnorm(h, bp["out_norm"], cfg.norm_eps)
+    x = x + h
+    up = jnp.einsum("bsd,dtf->bstf", h, bp["w_up"].astype(dt))
+    y = jax.nn.gelu(up[..., 0, :]) * up[..., 1, :]
+    return x + jnp.einsum("bsf,fd->bsd", y, bp["w_down"].astype(dt)), state
+
+
+def _slstm_scan(pre, rs, state):
+    """The sLSTM time loop as a ``lax.scan``, one step per token.
+
+    pre: {g: (B, S, nh, hd) f32} gate pre-activations without the
+    recurrent part; rs: {g: (nh, hd, hd)}; state: (c, n, h, m), each
+    (B, nh, hd). Returns (hs (B, S, nh, hd) f32, final state)."""
 
     def step(carry, inp):
         c, n, h, m = carry
         ip, fp, zp, op = inp  # (B, nh, hd)
         rec = {
-            g: jnp.einsum("bhk,hkj->bhj", h, bp[f"r_{g}"]) for g in ("i", "f", "z", "o")
+            g: jnp.einsum("bhk,hkj->bhj", h, rs[g]) for g in ("i", "f", "z", "o")
         }
         ip, fp, zp, op = (
             ip + rec["i"],
@@ -323,12 +348,20 @@ def slstm_seq(bp, cfg: ModelConfig, x, state=None):
 
     xs = tuple(pre[g].transpose(1, 0, 2, 3) for g in ("i", "f", "z", "o"))
     state, hs = lax.scan(step, state, xs)
-    h = hs.transpose(1, 0, 2, 3).reshape(B, S, d).astype(dt)
-    h = L.rmsnorm(h, bp["out_norm"], cfg.norm_eps)
-    x = x + h
-    up = jnp.einsum("bsd,dtf->bstf", h, bp["w_up"].astype(dt))
-    y = jax.nn.gelu(up[..., 0, :]) * up[..., 1, :]
-    return x + jnp.einsum("bsf,fd->bsd", y, bp["w_down"].astype(dt)), state
+    return hs.transpose(1, 0, 2, 3), state
+
+
+def _slstm_kernel(pre, rs, state):
+    """``_slstm_scan``'s contract, the time loop inside the Pallas kernel
+    pair (kernels/slstm.py): the gates side by side, head-major."""
+    B, S, nh, hd = pre["i"].shape
+    d = nh * hd
+    g = jnp.stack([pre[k] for k in "ifzo"], axis=3)  # (B, S, nh, 4, hd)
+    hs, st = kops.slstm_recurrence(
+        g.transpose(1, 0, 2, 3, 4).reshape(S, B, 4 * d),
+        [rs[k] for k in "ifzo"], [a.reshape(B, d) for a in state])
+    return (hs.transpose(1, 0, 2).reshape(B, S, nh, hd),
+            tuple(a.reshape(B, nh, hd) for a in st))
 
 
 def slstm_init_state(cfg: ModelConfig, B: int):

@@ -1,11 +1,13 @@
 """The meta-path Pallas kernels compile for a TPU v5e at the xlstm-350m
-packed width, without a chip.
+packed width, and the sLSTM time-loop kernels at the benchmark cell's
+shapes, without a chip.
 
 Each test lowers one kernel wrapper of ``repro.kernels.ops``, as the CLI
-reaches it (same block choice), in compiled mode for one described v5e
-chip and asserts the Mosaic kernel (``tpu_custom_call``) is in the
-compiled program. What interpret mode cannot show — a slice off the
-tiling, too much VMEM, a block that does not divide — fails here.
+reaches it (same block choice), or one sLSTM block's value and gradient,
+in compiled mode for one described v5e chip and asserts the Mosaic
+kernel (``tpu_custom_call``) is in the compiled program. What interpret
+mode cannot show — a slice off the tiling, too much VMEM, a block that
+does not divide — fails here.
 
 The v5e topology is described inside a module fixture, never at import:
 only one process may load the TPU library, and under several test workers
@@ -112,3 +114,28 @@ def test_robust_reduce(one_chip):
         lambda x: ops.robust_reduce(x, trim=1, interpret=False),
         _sds(one_chip, (4, ROWS, 128)))
     assert "tpu_custom_call" in txt
+
+
+def test_slstm_block_value_and_grad(one_chip, monkeypatch):
+    """One xlstm-350m sLSTM block's value and gradient at the benchmark
+    cell's shapes (B=4, S=512, bf16 learner weights and activations)
+    holds the forward and backward kernels of its time loop."""
+    from repro.configs import get_config
+    from repro.models import xlstm
+
+    # the model decides from the platform; compile it as on the chip
+    monkeypatch.setattr(ops, "_platform", lambda: "tpu")
+    cfg = get_config("xlstm-350m")
+    bp = jax.tree.map(
+        lambda a: _sds(one_chip, a.shape, jnp.bfloat16),
+        jax.eval_shape(lambda: xlstm._init_slstm_block(
+            jax.random.PRNGKey(0), cfg)))
+    x = _sds(one_chip, (4, 512, cfg.d_model), jnp.bfloat16)
+    txt = _compiled_text(jax.value_and_grad(
+        lambda p, x: jnp.sum(xlstm.slstm_seq(p, cfg, x)[0].astype(
+            jnp.float32))), bp, x)
+    calls = [ln for ln in txt.splitlines()
+             if "custom-call(" in ln and "tpu_custom_call" in ln]
+    assert len(calls) == 2, calls
+    assert any("slstm_fwd" in ln for ln in calls)
+    assert any("slstm_bwd" in ln for ln in calls)
