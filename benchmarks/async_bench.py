@@ -21,8 +21,9 @@ would idle >= 40% of wall-clock; applied staleness stays <= tau on every
 tick. A modeled layer prices the per-tick wire under the same profile
 (roofline.topology_wire_bytes "async" arm).
 
-Prints ``async,...`` CSV lines; ``--json PATH`` dumps every row as the
-CI artifact. ``--smoke`` shrinks steps for CI.
+Prints ``async,...`` CSV lines; ``--json PATH`` dumps every row.
+``--smoke`` shrinks steps; tests/test_async.py (A8) holds the
+acceptance rows at that size.
 """
 from __future__ import annotations
 
@@ -204,6 +205,6 @@ if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true", help="few steps (CI)")
     ap.add_argument("--json", default=None, metavar="PATH",
-                    help="also dump all rows as JSON (CI artifact)")
+                    help="also dump all rows as JSON")
     args = ap.parse_args()
     main(quick=args.smoke, json_path=args.json)

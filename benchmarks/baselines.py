@@ -17,7 +17,7 @@ CASES = [
 def main(quick: bool = False):
     """Primary metric: samples to a target loss (the paper's section-IV
     comparison is accuracy-per-samples; wall-clock communication costs are
-    covered by the dry-run roofline, EXPERIMENTS.md section Roofline).
+    not modeled here).
 
     Note: on this low-noise CPU task the paper's *final-accuracy* gaps
     between the averaging family and Downpour/EAMSGD largely vanish —

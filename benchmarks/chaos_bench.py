@@ -19,9 +19,9 @@ Arms:
                     ``latest_verified_checkpoint`` must fall back to the
                     previous snapshot bit-exactly
 
-Prints ``chaos,...`` CSV lines; ``--json PATH`` dumps every row as the
-CI artifact (gated by benchmarks/expected/chaos.json via
-tools/bench_compare.py). ``--smoke`` shrinks steps for CI.
+Prints ``chaos,...`` CSV lines; ``--json PATH`` dumps every row.
+``--smoke`` shrinks steps; tests/test_chaos.py (CH6) holds the
+acceptance row at that size.
 """
 from __future__ import annotations
 
@@ -258,6 +258,6 @@ if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true", help="few steps (CI)")
     ap.add_argument("--json", default=None, metavar="PATH",
-                    help="also dump all rows as JSON (CI artifact)")
+                    help="also dump all rows as JSON")
     args = ap.parse_args()
     main(quick=args.smoke, json_path=args.json)

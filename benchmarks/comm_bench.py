@@ -1,13 +1,11 @@
-"""Meta-communication benchmark: step time, bytes-on-wire, and final loss
-per compression scheme (the repro.comm subsystem).
+"""Meta-communication benchmark: bytes-on-wire and final loss per
+compression scheme (the repro.comm subsystem).
 
 Two layers of numbers:
 
-1. *Measured* — jitted meta-step wall time and the reducer's own
-   ``comm_bytes`` metrics on the teacher-classification MLP, plus final
-   training loss so compression quality is visible next to its savings.
-   CPU step times are not TPU-representative (and interpret-mode Pallas
-   slower still); the bytes and loss columns are the point.
+1. *Measured* — the reducer's own ``comm_bytes`` metrics on the
+   teacher-classification MLP, plus final training loss so compression
+   quality is visible next to its savings.
 2. *Modeled* — roofline.meta_wire_bytes on a full-scale config
    (qwen3-1.7b), showing what each scheme ships per meta step at
    production size and the resulting ICI link time.
@@ -28,7 +26,7 @@ if __package__ in (None, ""):  # `python benchmarks/comm_bench.py --smoke`
     sys.path.insert(0, os.path.join(_root, "src"))
     sys.path.insert(0, _root)
 
-from benchmarks.common import CLASSES, D_IN, HIDDEN, run_mlp, timeit
+from benchmarks.common import CLASSES, D_IN, HIDDEN, run_mlp
 from repro.configs.base import CommConfig, MAvgConfig, get_config
 from repro.core.meta import init_state, make_meta_step
 from repro.data import classif_batch_fn
@@ -50,7 +48,7 @@ def measured(quick: bool, *, P=4, K=4, mu=0.7, use_pallas=False):
                           use_pallas=use_pallas)
         losses, acc = run_mlp("mavg", P=P, K=K, mu=mu, steps=steps, comm=comm)
 
-        # one jitted step on a fixed batch for timing + metrics
+        # one jitted step on a fixed batch for the reducer's metrics
         cfg = MAvgConfig(algorithm="mavg", num_learners=P, k_steps=K,
                          learner_lr=0.2, momentum=mu, comm=comm)
         params = mlp_init(jax.random.PRNGKey(0), D_IN, HIDDEN, CLASSES)
@@ -58,8 +56,6 @@ def measured(quick: bool, *, P=4, K=4, mu=0.7, use_pallas=False):
         step = jax.jit(make_meta_step(mlp_loss, cfg))
         b = classif_batch_fn(D_IN, CLASSES, P, K, 16)(jax.random.PRNGKey(1), 0)
         _, m = step(state, b)
-        t_us = timeit(lambda s, bb: step(s, bb)[0], state, b,
-                      iters=3 if quick else 10, warmup=1)
 
         wire = float(m["comm_bytes"])
         dense_b = float(m["comm_bytes_dense"])
@@ -68,7 +64,6 @@ def measured(quick: bool, *, P=4, K=4, mu=0.7, use_pallas=False):
             dense_loss = final
         print(f"comm,{scheme},bytes_wire,{wire:.0f},B")
         print(f"comm,{scheme},compression,{dense_b / wire:.2f},x")
-        print(f"comm,{scheme},step_time,{t_us:.0f},us")
         print(f"comm,{scheme},final_loss,{final:.4f},"
               f"{final / dense_loss:.3f}x_dense")
         print(f"comm,{scheme},val_acc,{acc:.3f},frac")
@@ -90,6 +85,6 @@ def main(quick: bool = False):
 if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
-                    help="few steps / few timing iters (CI)")
+                    help="few steps (CI)")
     args = ap.parse_args()
     main(quick=args.smoke)

@@ -84,17 +84,3 @@ def write_rows(path: str, rows: list, suite: str) -> str:
     sink.close()
     return path
 
-
-def timeit(fn, *args, iters: int = 10, warmup: int = 2):
-    """Median wall-clock of ``fn(*args)`` in µs, through the shared
-    steady-state harness (obs.profile: warmup, block_until_ready,
-    median-of-N). Use ``steady(...)`` when the IQR noise bar is wanted
-    too — every reported bench number shares one methodology."""
-    return steady(fn, *args, iters=iters, warmup=warmup).median_us
-
-
-def steady(fn, *args, iters: int = 10, warmup: int = 2):
-    """The full ``obs.profile.Timing`` (median + IQR) of ``fn(*args)``."""
-    from repro.obs.profile import steady_timeit
-
-    return steady_timeit(fn, *args, iters=iters, warmup=warmup)

@@ -30,8 +30,8 @@ def sweep(mu, seeds=(0, 1, 2), lr=0.15):
 def _time_proxy(acc, comm_ratio: float):
     """Simulated wall-clock to equal samples: N meta-steps cost
     N * (K * t_local + t_comm) with t_comm = comm_ratio * t_local.
-    comm_ratio comes from the dry-run roofline (qwen3 train_4k:
-    collective term / compute term per meta-step, see EXPERIMENTS.md)."""
+    comm_ratio is a modeled ratio (collective term / compute term per
+    meta-step), not a measurement."""
     out = {}
     for K in KS:
         N = TOTAL_LOCAL_STEPS // K
@@ -51,8 +51,8 @@ def main(quick: bool = False, comm_ratio: float = 14.0):
     # Lemma 7: momentum prefers equal-or-smaller K
     assert k_opt7 <= max(k_opt0, 8), (k_opt0, k_opt7)
     # ...and wins outright once communication is priced in (the paper's
-    # low-communication-cost claim). comm_ratio=14 measured by the
-    # dry-run roofline for qwen3-1.7b train_4k on the single-pod mesh.
+    # low-communication-cost claim). comm_ratio=14 is an assumed
+    # collective-to-compute ratio for a pod-scale mesh.
     times = _time_proxy(acc0, comm_ratio)
     eff = {K: acc0[K] / times[K] for K in KS}
     k_opt_time = max(eff, key=eff.get)

@@ -21,9 +21,9 @@ Arms:
                   final state must be BITWISE identical (the hooks cost
                   nothing when they don't act)
 
-Prints ``robust,...`` CSV lines; ``--json PATH`` dumps every row as the
-CI artifact (gated by benchmarks/expected/robust.json via
-tools/bench_compare.py). ``--smoke`` shrinks steps for CI.
+Prints ``robust,...`` CSV lines; ``--json PATH`` dumps every row.
+``--smoke`` shrinks steps; tests/test_robust.py (R12) holds the
+acceptance row at that size.
 """
 from __future__ import annotations
 
@@ -262,6 +262,6 @@ if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true", help="few steps (CI)")
     ap.add_argument("--json", default=None, metavar="PATH",
-                    help="also dump all rows as JSON (CI artifact)")
+                    help="also dump all rows as JSON")
     args = ap.parse_args()
     main(quick=args.smoke, json_path=args.json)

@@ -4,24 +4,19 @@
   E2 mu_p_sweep.py      Figures 9-12 / Lemma 6 (optimal mu grows with P)
   E3 k_sweep.py         Lemmas 5 & 7 (optimal K > 1; momentum shrinks K)
   E4 baselines.py       section IV baselines (Downpour, EAMSGD, sync)
-  K  kernel_bench.py    fused block-momentum + flash-attention kernels
   C  comm_bench.py      meta-communication compression (repro.comm)
   T  topology_bench.py  meta-mixing topologies x comm (repro.topology)
   L  elastic_bench.py    elastic membership / hetero-K / time-varying gossip
   A  async_bench.py      async bounded-staleness server vs the barrier
   X  chaos_bench.py      fault injection + supervised recovery (repro.chaos)
   B  robust_bench.py     Byzantine-tolerant aggregation accept (repro.robust)
-  P  pack_bench.py      packed flat meta-plane parity / launches (repro.pack)
-  R  roofline_table.py  section Dry-run / Roofline aggregation
 
 Prints ``name,...`` CSV lines. ``--quick`` shrinks steps/seeds (default
 here so `python -m benchmarks.run` finishes on CPU in ~15 min); pass
-``--full`` for the EXPERIMENTS.md-grade numbers.
-
-Every suite's result rows are also appended to a per-suite trajectory
-store ``<bench-dir>/BENCH_<suite>.json`` (obs.baseline) — the cross-run
-history ``tools/bench_compare.py`` gates against the committed baselines
-in ``benchmarks/expected/``. ``--bench-dir ''`` disables the append.
+``--full`` for the longer runs behind DESIGN.md's tables. Every number
+here comes from a CPU run: losses, samples and modeled bytes, never a
+speed (the chip benchmark is ``bench/run.py``). The acceptance criteria
+of the topology, async, chaos and robust suites are tier-1 tests.
 """
 from __future__ import annotations
 
@@ -38,10 +33,8 @@ def main() -> None:
                     help="explicit form of the default (smoke-sized "
                          "suites); mutually exclusive with --full")
     ap.add_argument("--only", nargs="*", default=None,
-                    help="subset: convergence mu_p k baselines kernel comm topology elastic async chaos robust pack roofline")
-    ap.add_argument("--bench-dir", default="bench_out",
-                    help="directory of the BENCH_<suite>.json trajectory "
-                         "stores ('' = don't append)")
+                    help="subset: convergence mu_p k baselines comm "
+                         "topology elastic async chaos robust ablations")
     args = ap.parse_args()
     if args.full and args.quick:
         ap.error("--full and --quick are mutually exclusive")
@@ -55,30 +48,24 @@ def main() -> None:
         comm_bench,
         convergence,
         k_sweep,
-        kernel_bench,
         mu_p_sweep,
         elastic_bench,
-        pack_bench,
         robust_bench,
-        roofline_table,
         topology_bench,
     )
 
     suites = {
-        "kernel": lambda: kernel_bench.main(quick=quick),
         "comm": lambda: comm_bench.main(quick=quick),
         "topology": lambda: topology_bench.main(quick=quick),
         "elastic": lambda: elastic_bench.main(quick=quick),
         "async": lambda: async_bench.main(quick=quick),
         "chaos": lambda: chaos_bench.main(quick=quick),
         "robust": lambda: robust_bench.main(quick=quick),
-        "pack": lambda: pack_bench.main(quick=quick),
         "convergence": lambda: convergence.main(quick=quick),
         "baselines": lambda: baselines.main(quick=quick),
         "k": lambda: k_sweep.main(quick=quick),
         "mu_p": lambda: mu_p_sweep.main(quick=quick),
         "ablations": lambda: ablations.main(quick=quick),
-        "roofline": roofline_table.main,
     }
     if args.only:
         suites = {k: v for k, v in suites.items() if k in args.only}
@@ -96,24 +83,6 @@ def main() -> None:
                    and r.get("ok") is False]
             if bad:
                 raise SystemExit(f"{len(bad)} cell(s) not ok")
-            if args.bench_dir and rows:
-                from repro.obs.baseline import (
-                    append_trajectory, trajectory_path,
-                )
-
-                # the envelope convention of benchmarks/common.write_rows:
-                # bench-local "kind" taxonomies ride as "row_kind"
-                recs = []
-                for r in rows:
-                    if not isinstance(r, dict):
-                        continue
-                    rec = dict(r)
-                    if rec.get("kind") not in (None, "row"):
-                        rec["row_kind"] = rec.pop("kind")
-                    recs.append({"kind": "row", **rec})
-                path = trajectory_path(args.bench_dir, name)
-                append_trajectory(path, name, recs)
-                print(f"bench,{name},trajectory,{path}")
             print(f"bench,{name},{(time.time() - t0) * 1e6:.0f},ok")
         except (Exception, SystemExit) as e:
             # SystemExit is how benches signal failed cells from main();

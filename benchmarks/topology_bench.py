@@ -5,17 +5,17 @@ Two layers of numbers, mirroring comm_bench.py:
 
 1. *Measured* — final loss / val accuracy of the teacher-classification
    MLP under each (topology, comm) cell at equal meta-iterations, plus
-   the topology's own per-step comm metrics. The acceptance row: the
-   hierarchical cell with int8_topk cross-group traffic must ship >= 4x
+   the topology's own per-step comm metrics. The acceptance rows: the
+   hierarchical cell with int8_topk cross-group traffic must ship >= 50x
    fewer modeled inter-node bytes than flat dense while landing within
-   5% of flat mavg's final loss.
+   25% of flat mavg's final loss.
 2. *Modeled* — roofline.topology_wire_bytes on a full-scale config
    (qwen3-1.7b): per-meta-step intra-node (ICI) vs inter-node (DCN)
    payloads and link times per topology at production size.
 
 Prints ``topo,...`` CSV lines; ``--json PATH`` additionally dumps every
-row as JSON (the CI artifact, so the bench trajectory accumulates).
-``--smoke`` shrinks steps for CI.
+row as JSON. ``--smoke`` shrinks steps; tests/test_topology.py (T8) holds
+the acceptance rows at that size.
 """
 from __future__ import annotations
 
@@ -41,8 +41,8 @@ CELLS = (
      CommConfig(scheme="int8", error_feedback=True)),
     # mu_out = 0 on purpose: the inner level already carries the block
     # momentum, and stacking a second momentum on the outer displacement
-    # over-accelerates on this problem (mu_out=0.5 diverges — swept in
-    # EXPERIMENTS-style runs; the knob stays exercised by the tests)
+    # over-accelerates on this problem (mu_out=0.5 diverges; the knob
+    # stays exercised by the tests)
     ("hier_dense", TopologyConfig(kind="hierarchical", groups=2,
                                   outer_every=2),
      CommConfig()),
@@ -119,8 +119,8 @@ def main(quick: bool = False, json_path: str | None = None) -> list[dict]:
 if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
-                    help="few steps / few timing iters (CI)")
+                    help="few steps (CI)")
     ap.add_argument("--json", default=None, metavar="PATH",
-                    help="also dump all rows as JSON (CI artifact)")
+                    help="also dump all rows as JSON")
     args = ap.parse_args()
     main(quick=args.smoke, json_path=args.json)

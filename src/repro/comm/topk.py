@@ -22,7 +22,7 @@ slots are exact zeros and are never selected (the ``ab > 0`` guard), but
 they do inflate ``k = round(k_frac * n)`` by the pad fraction —
 negligible on the real configs, conservative (ships more) on tiny ones.
 Packed-vs-per-leaf top-k parity is pinned at the trajectory level in
-tests/test_pack.py and benchmarks/pack_bench.py.
+tests/test_pack.py.
 """
 from __future__ import annotations
 

@@ -501,9 +501,9 @@ def make_jit_meta_step(loss_fn: LossFn, cfg: MAvgConfig, reducer=None,
     donated to the step: XLA aliases every (rows, 128) plane of the input
     MetaState to the corresponding output plane and updates it in place,
     so the meta phase holds ONE copy of the state live instead of two —
-    peak meta-phase HBM at the 405B packed config drops ~2x (DESIGN.md
-    §10, measured in benchmarks/pack_bench.py). Numerics are unchanged:
-    donation is pure buffer aliasing.
+    the compiled meta mix's peak state at xlstm-350m's packed planes
+    halves (DESIGN.md §10, tests/test_zero_copy.py ZC5). Numerics are
+    unchanged: donation is pure buffer aliasing.
 
     The contract the caller signs: the state passed in is DEAD after the
     call (jax raises on re-use). Work off the returned state only —

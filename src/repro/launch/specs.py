@@ -1,5 +1,5 @@
 """Abstract input specs (ShapeDtypeStruct) + sharding assembly for the
-dry-run and the real launchers.
+launchers and for compiles against a described chip.
 
 input_specs() provides weak-type-correct, shardable stand-ins for every
 model input — no device allocation — including the stub modality
@@ -153,7 +153,7 @@ def state_shardings(cfg: ModelConfig, mcfg: MAvgConfig, mesh, *,
     'fsdp' — weights fully sharded over 'model' on their largest dim and
         the learner's local batch sharded over 'model' (ZeRO-3 style:
         per-layer weight all-gather instead of activation all-reduce —
-        wins when B*S >> d_model, see EXPERIMENTS.md section Perf).
+        wins when B*S >> d_model).
     """
     laxes = meshlib.learner_axes(mesh, hierarchical=hierarchical)
     fsdp = meshlib.fsdp_axes(mesh, hierarchical=hierarchical)

@@ -8,8 +8,7 @@ width fits one 16 GiB v5e chip with bf16 learner copies
 every chip of the host, one (n, 1) ('data', 'model') mesh from
 ``launch/mesh.py``, with the learner stack and the batches sharded on
 'data'; without it everything runs on the default device. No pod-scale
-mesh is built here — the 256/512-chip programs are compiled, not run, by
-``launch/dryrun.py``.
+mesh is built here.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.train --arch qwen3-1.7b \

@@ -62,7 +62,7 @@ def prefill(params, cfg: ModelConfig, batch, cache_len: int, *,
 
 # ---------------------------------------------------------------------------
 # batch construction (real arrays for smoke/train, ShapeDtypeStructs for
-# dry-run lowering)
+# abstract lowering)
 # ---------------------------------------------------------------------------
 
 

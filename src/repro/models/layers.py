@@ -226,12 +226,12 @@ def chunked_attention(
 
 
 # Sequences above this use blockwise online-softmax attention in jnp
-# (never materialising the S x S score tensor at once). Perf iteration 3
-# (EXPERIMENTS.md section Perf) tried lowering this to 2048 for train_4k
-# and was REFUTED: the unfused jnp online-softmax touches each score
-# block ~6x (XLA writes every intermediate), 2.5x more HBM traffic than
-# the one-shot S^2 softmax. The true fix on TPU is the Pallas flash
-# kernel (ops.flash_attention): one VMEM pass, HBM traffic = q+k+v+o.
+# (never materialising the S x S score tensor at once). Lowering this to
+# 2048 for train_4k was tried and REFUTED: the unfused jnp online-softmax
+# touches each score block ~6x (XLA writes every intermediate), 2.5x more
+# HBM traffic than the one-shot S^2 softmax. The true fix on TPU is the
+# Pallas flash kernel (ops.flash_attention): one VMEM pass, HBM traffic =
+# q+k+v+o.
 # (env override kept for reproducing that measurement)
 import os as _os
 

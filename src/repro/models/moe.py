@@ -27,10 +27,10 @@ from repro.models.layers import _dense_init, init_mlp, swiglu
 EXPERT_AXIS = None
 
 # Manual expert parallelism via shard_map (serving paths only — shard_map
-# does not compose with the learner vmap in this JAX version, measured in
-# EXPERIMENTS.md §Perf C). Each shard computes ONLY its local experts from
-# the replicated token block and contributes a partial (T, d) psum:
-# communication = one psum per layer, no replicate-reshard fallbacks.
+# does not compose with the learner vmap in this JAX version). Each shard
+# computes ONLY its local experts from the replicated token block and
+# contributes a partial (T, d) psum: communication = one psum per layer,
+# no replicate-reshard fallbacks.
 SHARD_MAP_MESH = None  # set by launchers to the active Mesh
 
 
@@ -46,8 +46,7 @@ def _constrain_experts(x, spec=None):
     The capacity gather's output sharding is ambiguous to GSPMD (indices
     sharded on E, source replicated); left alone it replicates x_e and
     then ALL-GATHERS the expert weights per layer (~34 GB/layer for
-    kimi-k2 — measured, EXPERIMENTS.md §Perf). No-op unless a launcher
-    called set_expert_axis.
+    kimi-k2). No-op unless a launcher called set_expert_axis.
     """
     if EXPERT_AXIS is None:
         return x
@@ -162,7 +161,7 @@ def moe_layer(x, p, cfg: ModelConfig, dropless: bool = False):
     # Each expert shard accumulates its C tokens into a partial (T, d)
     # buffer; under GSPMD (E sharded over 'model') this lowers to one
     # all-reduce of (T, d) instead of a replicated (T*k, d) gather +
-    # segment-sum (perf iteration for kimi-k2, EXPERIMENTS.md §Perf).
+    # segment-sum.
     y_w = y_e * gate_tab[..., None].astype(dt)  # (E, C, d)
     out = jnp.zeros((T + 1, d), dt).at[dispatch.reshape(-1)].add(
         y_w.reshape(E * C, d), mode="drop"
@@ -185,8 +184,7 @@ def _experts_shard_map(xt, p, cfg: ModelConfig, gates, slot_expert,
     replicated token block to its local experts, runs the FFNs locally,
     and contributes a partial (T, d) output — combined with ONE psum.
     Communication per layer = one (T, d) all-reduce, versus the GSPMD
-    gather/scatter path's replicate-reshard fallbacks (EXPERIMENTS.md
-    §Perf C).
+    gather/scatter path's replicate-reshard fallbacks.
     """
     T, d = xt.shape
     E, k = cfg.num_experts, cfg.moe_top_k

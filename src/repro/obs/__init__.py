@@ -17,27 +17,16 @@ Three pieces, composable and individually optional:
 PackSpec hash, topology/reducer/elastic settings, jax/device info, and
 optionally the measured compiled-program cost (roofline.hlo_cost).
 
-PR 7 adds the measurement-and-judgment layer on top:
+Two judgment pieces sit on top:
 
-* ``profile`` (profile.py): the steady-state timing harness every
-  reported number flows through (warmup, block_until_ready, median +
-  IQR) and the measured-vs-modeled attribution join against
-  ``roofline.hlo_cost`` (achieved HBM GB/s, % of the machine's measured
-  roofline bound).
-* ``baseline`` (baseline.py): per-suite ``BENCH_<suite>.json`` trajectory
-  stores + committed baseline specs; ``tools/bench_compare.py`` turns
-  them into the CI regression gate.
+* ``profile`` (profile.py): a steady-state timing harness (warmup,
+  block_until_ready, median + IQR) and the measured-vs-modeled
+  attribution join against ``roofline.hlo_cost`` (achieved HBM GB/s,
+  % of a measured bandwidth bound), behind ``ObsConfig.attribution``.
 * ``health`` (health.py): declarative run-health rules over flushed
   metric windows -> structured ``alert`` records, with fatal rules
   halting the Trainer on a resumable checkpoint (``HealthHalt``).
 """
-from repro.obs.baseline import (
-    append_trajectory,
-    compare,
-    latest_rows,
-    load_trajectory,
-    trajectory_path,
-)
 from repro.obs.health import (
     DEFAULT_RULES,
     HealthHalt,
@@ -84,12 +73,8 @@ __all__ = [
     "Sink",
     "Timing",
     "Tracer",
-    "append_trajectory",
     "attribution_row",
-    "compare",
     "device_env",
-    "latest_rows",
-    "load_trajectory",
     "make_monitor",
     "make_sink",
     "measured_peak_gbps",
@@ -99,6 +84,5 @@ __all__ = [
     "profile_phases",
     "run_manifest",
     "steady_timeit",
-    "trajectory_path",
     "write_row",
 ]

@@ -1,8 +1,8 @@
 """Steady-state profiler attribution: measured wall-clock joined against
 the modeled roofline cost (DESIGN.md §11, "Measured performance").
 
-Every timing number this repo reports flows through ONE harness so the
-methodology is uniform and stated once:
+The Trainer's ``ObsConfig.attribution`` rows are timed through ONE
+harness so the methodology is uniform and stated once:
 
 * **steady state** — ``warmup`` untimed calls first, so compilation,
   autotuning and allocator warm-up never leak into a reported number;
@@ -19,9 +19,8 @@ own says nothing about whether a kernel is fast. Joining it against the
 compiled program's modeled HBM bytes (``roofline.hlo_cost.jit_cost``)
 yields achieved GB/s, and dividing by a measured peak bandwidth
 (``measured_peak_gbps`` — a jitted triad on this very machine, not a
-datasheet constant) yields % of the roofline bound: the number that is
-comparable across machines and across PRs, and the one
-``tools/bench_compare.py`` gates on.
+datasheet constant) yields % of the roofline bound. On the CPU these
+are counts, not speeds: the chip's timings come from ``bench/``.
 
 On CPU the Pallas kernels are timed through their jnp reference route
 (interpret mode executes the kernel body block-by-block in Python — its
@@ -202,8 +201,7 @@ def profile_phases(loss_fn, cfg, state, batches, lr=None, *, iters: int = 10,
     and, for the averaging algorithms, (c) the meta mix alone
     (``topology.mix`` on the current state's planes) — each joined
     against its own compiled-HLO modeled cost. The rows ride the same
-    sink envelope as step records (``kind: attribution``) and are what
-    ``pack_bench`` surfaces per config.
+    sink envelope as step records (``kind: attribution``).
 
     Profiling uses FUNCTIONAL (non-donated) step instances: a donated
     step kills its input buffers on first dispatch, and a timing loop

@@ -180,7 +180,7 @@ def _instr_bytes(ins: Instr, symtab, comps=None) -> float:
     Slicing ops only touch the sliced region, NOT the whole operand —
     charging a scan's dynamic-update-slice the full stacked output buffer
     every iteration would overcount a 4096-step scan by orders of
-    magnitude (caught against the xLSTM scan; EXPERIMENTS.md §Perf).
+    magnitude (caught against the xLSTM scan).
     XLA wraps the dus in a kLoop fusion whose *result type* is the full
     aliased buffer, so fusions are resolved through their root.
     """
@@ -354,7 +354,7 @@ def hlo_cost(hlo: str) -> HloCost:
 
 
 # ---------------------------------------------------------------------------
-# dry-run cost of a jitted function (AOT: no device allocation)
+# compiled cost of a jitted function (AOT: no device allocation)
 # ---------------------------------------------------------------------------
 
 
@@ -392,9 +392,9 @@ class JitCost:
 def jit_cost(fn, *abstract_args, **jit_kwargs) -> JitCost:
     """Lower + compile ``fn`` on abstract ShapeDtypeStruct args and read
     the costs off the compiled artifact — nothing is allocated or run, so
-    this works at full 405B scale on the CPU container (the dry-run
-    move). ``jit_kwargs`` pass to jax.jit; ``donate_argnums`` is how the
-    donated-vs-functional peak-memory comparison is produced."""
+    this works at full width on the CPU. ``jit_kwargs`` pass to jax.jit;
+    ``donate_argnums`` is how the donated-vs-functional peak-memory
+    comparison is produced."""
     import jax
 
     compiled = jax.jit(fn, **jit_kwargs).lower(*abstract_args).compile()

@@ -27,7 +27,7 @@ log = logging.getLogger(__name__)
 # name -> preference list of negative dims for the model (TP) axis.
 # (A variant placing the second/fsdp axis on head_dim instead of d_model
 # was tried and REFUTED — it doubled all-gather traffic on qwen1.5-110b
-# prefill without touching the all-reduce term; see EXPERIMENTS.md.)
+# prefill without touching the all-reduce term.)
 PREFS: dict[str, tuple[int, ...]] = {
     # attention (d, h, hd) / (h, hd, d)
     "wq": (-2, -3),

@@ -16,6 +16,10 @@ Invariants:
   A6  config validation: profiles that cannot honor the staleness bound,
       non-dense comm, and length mismatches are rejected eagerly.
   A7  work_completed matches the on-device fired_count accounting.
+  A8  benchmarks/async_bench.py at smoke size: at 4x skew async lands
+      within 5% of sync loss at equal samples, the barrier idles >= 40%,
+      async takes >= 1.5x fewer model ticks, and the skewed profile's
+      modeled wire stays at its recorded bytes.
 """
 import jax
 import jax.numpy as jnp
@@ -241,3 +245,32 @@ def test_a7_work_completed_matches_fired_counts():
     # a synchronous topology completes L blocks per tick
     flat = make_topology(MAvgConfig(num_learners=4, k_steps=2))
     assert flat.work_completed(9) == 40
+
+
+# ---------------------------------------------------------------------------
+# A8: the async suite's acceptance experiment (benchmarks/async_bench.py)
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def async_rows(run_bench):
+    return run_bench("async_bench")
+
+
+@pytest.mark.parametrize("kind,cell,field,lo,hi", [
+    pytest.param("async_accept", None, "loss_vs_sync_at_equal_samples",
+                 None, 1.05, id="loss_vs_sync"),
+    pytest.param("async_accept", None, "sync_idle_frac", 0.4, None,
+                 id="sync_idle"),
+    # model ticks, not a wall clock
+    pytest.param("async_accept", None, "wall_clock_speedup", 1.5, None,
+                 id="tick_speedup"),
+    # modeled per-tick wire of the skewed profile (deterministic)
+    pytest.param("async_model", "async_skew4", "inter_bytes",
+                 None, 37_849_923_584 * 1.02, id="skew4_inter_bytes"),
+])
+def test_a8_bench_acceptance(async_rows, kind, cell, field, lo, hi):
+    (row,) = [r for r in async_rows
+              if r["kind"] == kind and r.get("cell") == cell]
+    assert lo is None or row[field] >= lo, (field, row[field])
+    assert hi is None or row[field] <= hi, (field, row[field])

@@ -14,6 +14,11 @@ The contract has three legs, each pinned here:
    Supervisor rolls back through the verified chain and completes the
    target steps with schema-valid fault/recovery telemetry; a sticky
    fault exhausts the bounded retry budget instead of looping forever.
+
+CH6 holds benchmarks/chaos_bench.py's acceptance experiment at smoke size:
+under the standard fault schedule the supervised run lands within 5% of
+the fault-free loss with a finite state, a torn save falls back bit-exactly,
+and recovery stays within 3 retries.
 """
 import dataclasses
 import importlib.util
@@ -576,3 +581,32 @@ def test_ch4_quarantine_hysteresis_extends_probation(tmp_path):
 def test_ch4_readmit_clean_windows_validation():
     with pytest.raises(AssertionError):
         RecoveryPolicy(readmit_clean_windows=0)
+
+
+# ---------------------------------------------------------------------------
+# CH6: the chaos suite's acceptance experiment (benchmarks/chaos_bench.py)
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def chaos_accept(run_bench):
+    (row,) = [r for r in run_bench("chaos_bench")
+              if r["kind"] == "chaos_accept"]
+    return row
+
+
+@pytest.mark.parametrize("field,lo,hi", [
+    # supervised run within 5% of the fault-free loss at equal samples
+    ("loss_vs_fault_free", None, 1.05),
+    # final state and every retained snapshot finite
+    ("state_finite", True, None),
+    # a torn save falls back to the last verified snapshot bit-exactly
+    ("resume_verified", True, None),
+    # recovery within the bounded retry budget
+    ("retries_used", None, 3),
+], ids=["loss_vs_fault_free", "state_finite", "resume_verified",
+        "retries_used"])
+def test_ch6_bench_acceptance(chaos_accept, field, lo, hi):
+    value = chaos_accept[field]
+    assert lo is None or value >= lo, (field, value)
+    assert hi is None or value <= hi, (field, value)

@@ -1,5 +1,6 @@
-"""Chunkwise-parallel mLSTM == step-recurrent mLSTM (perf iteration for
-xlstm-350m, EXPERIMENTS.md section Perf). Exactness matters: the chunked
+"""Chunkwise-parallel mLSTM == step-recurrent mLSTM (xlstm-350m trains in
+the chunkwise form: the recurrent form's backward does not fit one chip,
+PERF.md section 6). Exactness matters: the chunked
 form is used for training, the recurrent form for decode, and they must
 agree or train/serve diverge."""
 import dataclasses

@@ -32,6 +32,9 @@ The contract, pinned here:
       stays 0) — the threat model repro.robust exists for.
   R11 config validation: impossible trims and flat-topology quarantine
       are rejected up front.
+  R12 benchmarks/robust_bench.py at smoke size: under sticky finite
+      corruption the robust mix lands within 5% of fault-free loss with a
+      finite state and no rollback, while the plain mean degrades.
 """
 import dataclasses as dc
 import importlib.util
@@ -522,3 +525,31 @@ def test_r11_config_validation():
     assert make_robust(
         MAvgConfig(algorithm="mavg", num_learners=4, k_steps=2)
     ) is None
+
+
+# ---------------------------------------------------------------------------
+# R12: the robust suite's acceptance experiment (benchmarks/robust_bench.py)
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def robust_accept(run_bench):
+    (row,) = [r for r in run_bench("robust_bench")
+              if r["kind"] == "robust_accept"]
+    return row
+
+
+@pytest.mark.parametrize("field,lo,hi", [
+    # robust mix within 5% of fault-free under sticky finite corruption
+    ("loss_vs_fault_free", None, 1.05),
+    # the plain mean degrades under the same corruption: the threat is real
+    ("mean_degrades", True, None),
+    # graceful degradation: no supervisor rollback
+    ("rollbacks", None, 0),
+    ("state_finite", True, None),
+], ids=["loss_vs_fault_free", "mean_degrades", "rollbacks",
+        "state_finite"])
+def test_r12_bench_acceptance(robust_accept, field, lo, hi):
+    value = robust_accept[field]
+    assert lo is None or value >= lo, (field, value)
+    assert hi is None or value <= hi, (field, value)

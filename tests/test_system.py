@@ -6,7 +6,7 @@
 * The jitted meta-step runs unchanged under a real multi-device mesh with
   the learner axis sharded (subprocess with 8 host devices) and produces
   the same losses as the single-device run — the SPMD-correctness
-  integration test backing the dry-run.
+  integration test of the sharded meta step.
 """
 import json
 import os

@@ -15,6 +15,10 @@ Invariants:
       resumed run stays bit-identical.
   T7  modeled inter-node bytes: hierarchical with int8_topk cross-group
       <= 1/4 of flat dense at equal meta-iterations.
+  T8  benchmarks/topology_bench.py at smoke size: hierarchical int8_topk
+      ships >= 50x fewer modeled inter bytes than flat at qwen3-1.7b
+      (and its recorded bytes), within 25% of flat's loss; flat dense's
+      fixed-seed loss stays within 15% of its recorded value.
 """
 import dataclasses as dc
 
@@ -261,6 +265,36 @@ def test_t7_hierarchical_inter_bytes_reduction():
         num_learners=L,
     )
     assert goss["inter_bytes"] == 2 * flat["inter_bytes"]  # ring degree 2
+
+
+# ---------------------------------------------------------------------------
+# T8: the topology suite's acceptance experiment (benchmarks/topology_bench.py)
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def topo_rows(run_bench):
+    return run_bench("topology_bench")
+
+
+@pytest.mark.parametrize("kind,cell,field,lo,hi", [
+    # hierarchical + int8_topk ships >= 50x fewer modeled inter bytes
+    pytest.param("topo_accept", None, "inter_reduction_vs_flat", 50, None,
+                 id="inter_reduction"),
+    # fixed-seed flat dense loss, 15% over its recorded 0.713
+    pytest.param("topo_measured", "flat_dense", "final_loss",
+                 None, 0.713012957572937 * 1.15, id="flat_dense_loss"),
+    pytest.param("topo_measured", "hier_int8topk_outer", "vs_flat",
+                 None, 1.25, id="hier_int8topk_vs_flat"),
+    # modeled inter-tier bytes (deterministic)
+    pytest.param("topo_model", "hier_int8topk_outer", "inter_bytes",
+                 None, 861_065_600 * 1.02, id="hier_int8topk_inter_bytes"),
+])
+def test_t8_bench_acceptance(topo_rows, kind, cell, field, lo, hi):
+    (row,) = [r for r in topo_rows
+              if r["kind"] == kind and r.get("cell") == cell]
+    assert lo is None or row[field] >= lo, (field, row[field])
+    assert hi is None or row[field] <= hi, (field, row[field])
 
 
 # ---------------------------------------------------------------------------

@@ -19,10 +19,16 @@ Invariants:
        plane, bitwise, on both the kernel and oracle routes; the EF
        residual it emits equals the separate tree_sub(delta, c) pass it
        replaces, bitwise (CompressedReducer._compress_residual).
+  ZC5  on xlstm-350m's full-width planes (abstract, nothing allocated):
+       donation cuts the meta mix's compiled peak state to <= 0.6x the
+       functional program's; the fused kernel removes the unfused route's
+       read-back of the new meta plane; compress-only moves >= 1 plane less
+       than pack_update on a zero gp plane.
 """
 import dataclasses as dc
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -319,3 +325,105 @@ def test_zc4_gossip_ef_trajectory_matches_pr4_route():
         np.asarray(new_res), np.asarray(tree_sub(d_ef, c_old))
     )
     assert wire == wire_old
+
+
+# ---------------------------------------------------------------------------
+# ZC5: memory and traffic on xlstm-350m's full-width meta planes
+# ---------------------------------------------------------------------------
+
+XL_L = 2  # the benchmark cell's learners, bf16 copies
+
+
+def _xl_plane():
+    """xlstm-350m's abstract f32 (rows, 128) meta plane and its bytes:
+    nothing is allocated, the programs below are only traced or compiled."""
+    from repro.configs.base import get_config
+    from repro.launch.specs import abstract_params
+    from repro.pack import make_pack_spec
+
+    spec = make_pack_spec(abstract_params(get_config("xlstm-350m")))
+    return (jax.ShapeDtypeStruct((spec.rows, 128), jnp.float32),
+            spec.plane_bytes("float32"))
+
+
+def _kernel_outputs_read_back(fn, *args):
+    """Bytes of Pallas kernel outputs that later ops of ``fn``'s traced
+    program read again. XLA cannot fuse across a pallas_call, so each is
+    one more HBM read of a plane the kernel has just written."""
+    written, nbytes = set(), 0
+    for eqn in jax.make_jaxpr(fn)(*args).jaxpr.eqns:
+        nbytes += sum(v.aval.size * v.aval.dtype.itemsize
+                      for v in eqn.invars
+                      if isinstance(v, jax.extend.core.Var) and v in written)
+        if eqn.primitive.name == "pallas_call":
+            written.update(eqn.outvars)
+    return nbytes
+
+
+def _donated_peak_state():
+    """Donating the state planes to the flat dense meta mix (learner mean
+    + fused momentum->broadcast) cuts the compiled program's peak state to
+    <= 0.6x the functional program's, through real input/output aliases."""
+    from repro.roofline.hlo_cost import jit_cost
+
+    plane, _ = _xl_plane()
+    lrn = jax.ShapeDtypeStruct((XL_L,) + plane.shape, jnp.bfloat16)
+
+    def meta_mix(gp, v, lrn):
+        a = jnp.mean(lrn.astype(jnp.float32), axis=0)
+        return ref.fused_momentum_broadcast_ref(gp, v, a, 0.7, 1.0, XL_L,
+                                                lrn.dtype)
+
+    fun = jit_cost(meta_mix, plane, plane, lrn)
+    don = jit_cost(meta_mix, plane, plane, lrn, donate_argnums=(0, 1, 2))
+    assert don.alias_bytes > 0
+    assert don.peak_state_bytes <= 0.6 * fun.peak_state_bytes, (don, fun)
+
+
+def _fused_broadcast_read_removed():
+    """The fused kernel emits the learner planes from the update itself;
+    the unfused route reads the new meta plane back to broadcast it."""
+    from repro.topology.base import block_momentum_update
+    from repro.utils import tree_broadcast_learners, tree_cast
+
+    plane, plane_b = _xl_plane()
+
+    def unfused(w, v, a):
+        gp, vv = block_momentum_update(w, v, a, mu=0.7, eta=1.0,
+                                       use_pallas=True)
+        return gp, vv, tree_broadcast_learners(tree_cast(gp, jnp.bfloat16),
+                                               XL_L)
+
+    def fused(w, v, a):
+        return ops.fused_momentum_broadcast(
+            w, v, a, mu=0.7, eta=1.0, num_learners=XL_L,
+            ldtype=jnp.bfloat16, use_pallas=True)
+
+    removed = (_kernel_outputs_read_back(unfused, plane, plane, plane)
+               - _kernel_outputs_read_back(fused, plane, plane, plane))
+    assert removed >= plane_b, removed / plane_b
+
+
+def _compress_only_traffic_removed():
+    """pack_compress drops the zero gp plane pack_update had to read: the
+    compiled program moves >= 1 plane less."""
+    from repro.roofline.hlo_cost import jit_cost
+
+    plane, plane_b = _xl_plane()
+    d = jax.ShapeDtypeStruct((XL_L,) + plane.shape, jnp.float32)
+    zero_gp = jit_cost(
+        lambda d, g, u: ops.pack_update(d, g, None, u, block=64,
+                                        use_pallas=False), d, plane, d)
+    compress = jit_cost(
+        lambda d, u: ops.pack_compress(d, u, block=64, use_pallas=False),
+        d, d)
+    removed = zero_gp.hbm_bytes - compress.hbm_bytes
+    assert removed >= plane_b, removed / plane_b
+
+
+@pytest.mark.parametrize("check", [
+    _donated_peak_state, _fused_broadcast_read_removed,
+    _compress_only_traffic_removed,
+], ids=lambda f: f.__name__.strip("_"))
+def test_zc5_xlstm350m_meta_planes(check):
+    check()
